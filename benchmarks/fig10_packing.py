@@ -44,26 +44,26 @@ def run_model(quick: bool = False):
 def run_measured(quick: bool = False):
     import jax
     import jax.numpy as jnp
-    from repro.utils.jaxcompat import shard_map
+    from repro.launch.mesh import make_host_mesh
     from repro.utils.timing import time_fn
 
     n_dev = jax.device_count()
     if n_dev < 2:
         csv_row("fig10/measured/skipped", 0.0, f"only {n_dev} device")
         return
-    mesh = jax.make_mesh((n_dev,), ("x",))
+    mesh = make_host_mesh((n_dev,), ("x",))
     sizes = [s // 4 for s in LENET_LAYER_BYTES]
     arrs = [jnp.ones((n_dev, s), jnp.float32) for s in sizes]
     packed = jnp.ones((n_dev, sum(sizes)), jnp.float32)
     from jax.sharding import PartitionSpec as P
     from functools import partial
 
-    @partial(shard_map, mesh=mesh, in_specs=(P("x"),) * len(arrs),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P("x"),) * len(arrs),
              out_specs=(P("x"),) * len(arrs), check_vma=False)
     def per_layer(*xs):
         return tuple(jax.lax.psum(x, "x") for x in xs)
 
-    @partial(shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
              check_vma=False)
     def one_packed(x):
         return jax.lax.psum(x, "x")

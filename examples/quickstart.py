@@ -24,10 +24,10 @@ def main():
     n_dev = jax.device_count()
     print(f"devices: {n_dev}")
     if n_dev >= 8:
-        mesh = make_host_mesh(n_data=2, n_model=2, n_pods=2)
+        mesh = make_host_mesh((2, 2, 2), ("pod", "data", "model"))
         n_pods = 2
     else:
-        mesh = make_host_mesh(n_data=1, n_model=1)
+        mesh = make_host_mesh((1, 1), ("data", "model"))
         n_pods = 1
 
     cfg = configs.get("gemma3-4b").reduced

@@ -203,10 +203,9 @@ def t_rounds(rounds, n_bytes: float, net=None, topology=None,
     """α–β time of a round structure with PER-MESSAGE link pricing: each
     round costs the max over its messages of ``link.α + frac·n·link.β``,
     rounds serialize. With a ``topology`` each message rides its own link
-    class; without one every message rides ``net`` — in which case this is
-    bitwise-equal to the closed ``cost_from_rounds`` formula (α + max_frac
-    ·n·β: the max is attained at the max-frac message and the float ops
-    match). ``wid`` restricts to messages touching that worker — its OWN
+    class; without one every message rides ``net``, which is what
+    ``Schedule.cost_from_rounds`` prices (α + max_frac·n·β per round).
+    ``wid`` restricts to messages touching that worker — its OWN
     pacing deadline on a heterogeneous mesh, where an intra-host pair
     finishes its segment early and waits on cross-host peers at the
     blocking recv rather than by sleeping."""

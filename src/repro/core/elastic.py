@@ -41,7 +41,6 @@ from repro.comm import schedules as comm_schedules
 from repro.core import compression as compression_lib
 from repro.core import costmodel
 from repro.core.easgd import EASGDConfig
-from repro.utils.jaxcompat import shard_map
 from repro.utils.pytree import tree_map
 
 
@@ -312,7 +311,7 @@ def _exchange_packed(state, grads, cfg, mesh, param_specs, pod_axis,
     in_specs = (P(), specs.params, specs.momentum, specs.center,
                 specs.ef_error if cfg.compression != "none" else P(),
                 grads_spec)
-    shmapped = shard_map(
+    shmapped = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs,
         out_specs=(P(), out_specs.params, out_specs.momentum,
                    out_specs.center,

@@ -21,7 +21,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 from jax.experimental import pallas as pl
 
 from repro.core.packing import ELASTIC_UPDATE_BLOCK
@@ -45,8 +44,9 @@ def _update_kernel(w_ref, v_ref, g_ref, c_ref, m_ref, w_out, v_out, c_out, *,
 def fused_elastic_update(w, v, g, c, mean_w, *, eta: float, rho: float,
                          mu: float, n_workers: int,
                          block: int = ELASTIC_UPDATE_BLOCK,
-                         interpret=True):
-    """All inputs 1-D, same length (packer-aligned). Returns (w', v', c')."""
+                         interpret: bool = False):
+    """All inputs 1-D, same length (packer-aligned). Returns (w', v', c').
+    Compiles for the TPU; pass ``interpret=True`` to run it on the CPU."""
     n = w.shape[0]
     bs = min(block, n)
     assert n % bs == 0, (n, bs, "pack with align=block")
@@ -83,6 +83,10 @@ def fused_elastic_update(w, v, g, c, mean_w, *, eta: float, rho: float,
 # extension, so LLVM cannot emit fma and the kernels match numpy bit for
 # bit (pinned at zero tolerance by tests/test_bucketing.py). Without the
 # flag the results are still correct to ~1 ulp, just not identical.
+#
+# They are interpret-only by design: the TPU has no f64, and they serve the
+# CPU-pinned host plane. Their callers (the p2p worker, the tests) pass
+# ``interpret=True``; without it Pallas refuses to lower them on the CPU.
 
 def _sync_easgd_kernel(w_ref, g_ref, c_ref, r_ref, w_out, c_out, *,
                        eta: float, rho: float, alpha_p: float, p: int):
@@ -119,7 +123,7 @@ def _bucket_grid(n: int, block: int):
 def fused_sync_easgd_update(w, grad, center, row, p: int,
                             eta: float, rho: float, *,
                             block: int = ELASTIC_UPDATE_BLOCK,
-                            interpret=True):
+                            interpret: bool = False):
     """One bucket's fused Sync EASGD update (worker rule + center pull in
     a single pass over the slices — five reads, two writes):
 
@@ -127,13 +131,14 @@ def fused_sync_easgd_update(w, grad, center, row, p: int,
         C' = C + ηρP(R/P − C)        (R = exchanged Σ_i W_i, pre-update)
 
     Returns ``(w', c')`` as f64 numpy arrays; the caller assigns them back
-    into its bucket slices."""
+    into its bucket slices. Interpret-only (f64, host plane): pass
+    ``interpret=True``."""
     n = w.shape[0]
     bs, grid = _bucket_grid(n, block)
     spec = pl.BlockSpec((bs,), lambda i: (i,))
     kernel = functools.partial(_sync_easgd_kernel, eta=eta, rho=rho,
                                alpha_p=(eta * rho) * p, p=p)
-    with enable_x64():
+    with jax.enable_x64(True):
         w_new, c_new = pl.pallas_call(
             kernel,
             grid=grid,
@@ -148,17 +153,18 @@ def fused_sync_easgd_update(w, grad, center, row, p: int,
 def fused_sync_sgd_update(center, vel, row, p: int,
                           eta: float, mu: float, *,
                           block: int = ELASTIC_UPDATE_BLOCK,
-                          interpret=True):
+                          interpret: bool = False):
     """One bucket's fused synchronous momentum-SGD master update:
 
         V̄' = μV̄ − η(R/P);  C' = C + V̄'     (R = exchanged Σ_i grad_i)
 
-    Returns ``(c', v̄')`` as f64 numpy arrays."""
+    Returns ``(c', v̄')`` as f64 numpy arrays. Interpret-only (f64, host
+    plane): pass ``interpret=True``."""
     n = center.shape[0]
     bs, grid = _bucket_grid(n, block)
     spec = pl.BlockSpec((bs,), lambda i: (i,))
     kernel = functools.partial(_sync_sgd_kernel, eta=eta, mu=mu, p=p)
-    with enable_x64():
+    with jax.enable_x64(True):
         c_new, v_new = pl.pallas_call(
             kernel,
             grid=grid,

@@ -9,8 +9,8 @@ sequential — running max/denominator/accumulator live in VMEM scratch
 across the k-block sweep (the standard TPU flash pattern; same math as the
 pure-JAX oracle models/attention.blocked_attention).
 
-Validated on CPU with interpret=True (kernels/ops.py flips interpretation
-off on real TPU). Causal + sliding-window masks are supported; GQA is
+Validated on CPU with interpret=True; it compiles for the TPU otherwise.
+Causal + sliding-window masks are supported; GQA is
 handled in the wrapper by expanding K/V head-wise (ops.flash_attention).
 """
 from __future__ import annotations
@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.utils.jaxcompat import pallas_tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -73,7 +71,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def flash_attention_bhsd(q, k, v, *, causal=True, window=0, block_q=128,
-                         block_k=128, interpret=True):
+                         block_k=128, interpret=False):
     """q, k, v: (BH, S, D) with matched heads (GQA expanded by the wrapper).
     Returns (BH, S, Dv)."""
     BH, S, D = q.shape
@@ -109,7 +107,7 @@ def flash_attention_bhsd(q, k, v, *, causal=True, window=0, block_q=128,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, Dv), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.utils.jaxcompat import pallas_tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -46,17 +44,17 @@ def _ce_kernel(h_ref, w_ref, t_ref, loss_ref, m_ref, l_ref, tgt_ref, *,
         jnp.int32, logits.shape, 1)
     logits = jnp.where(v_ids < vocab, logits, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, logits.max(axis=1))
+    m_prev = m_ref[...]                             # (bt, 1)
+    m_new = jnp.maximum(m_prev, logits.max(axis=1, keepdims=True))
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.exp(
-        logits - m_new[:, None]).sum(axis=1)
+    l_ref[...] = l_ref[...] * corr + jnp.exp(logits - m_new).sum(
+        axis=1, keepdims=True)
     m_ref[...] = m_new
 
     # target logit if it falls inside this vocab tile
-    t = t_ref[...]                                  # (bt,)
-    hit = (v_ids == t[:, None])
-    tgt_ref[...] = tgt_ref[...] + jnp.where(hit, logits, 0.0).sum(axis=1)
+    hit = v_ids == t_ref[...]                       # (bt, bv) vs (bt, 1)
+    tgt_ref[...] = tgt_ref[...] + jnp.where(hit, logits, 0.0).sum(
+        axis=1, keepdims=True)
 
     @pl.when(vj == n_v - 1)
     def _finalize():
@@ -65,8 +63,14 @@ def _ce_kernel(h_ref, w_ref, t_ref, loss_ref, m_ref, l_ref, tgt_ref, *,
 
 
 def fused_cross_entropy(h, w, targets, *, block_t: int = 256,
-                        block_v: int = 2048, interpret=True):
-    """h: (T, d); w: (d, V); targets: (T,) int32 -> per-token loss (T,)."""
+                        block_v: int = 512, interpret: bool = False):
+    """h: (T, d); w: (d, V); targets: (T,) int32 -> per-token loss (T,).
+
+    Targets, loss and the running statistics travel as (T, 1) columns: a
+    1-D int32 block must match XLA's 1024-element tiling, which a token
+    block of 256 does not; a column block needs only bt % 8 == 0.
+    ``block_v`` 2048 runs out of VMEM on a v5e at d = 3072 (the
+    double-buffered (d, bv) weight block); 512 and 1024 compile."""
     T, d = h.shape
     V = w.shape[1]
     bt = min(block_t, T)
@@ -86,17 +90,13 @@ def fused_cross_entropy(h, w, targets, *, block_t: int = 256,
         in_specs=[
             pl.BlockSpec((bt, d), lambda i, j: (i, 0)),
             pl.BlockSpec((d, bv), lambda i, j: (0, j)),
-            pl.BlockSpec((bt,), lambda i, j: (i,)),
+            pl.BlockSpec((bt, 1), lambda i, j: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((bt,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((T + pt,), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((bt,), jnp.float32),
-            pltpu.VMEM((bt,), jnp.float32),
-            pltpu.VMEM((bt,), jnp.float32),
-        ],
-        compiler_params=pallas_tpu_compiler_params(
+        out_specs=pl.BlockSpec((bt, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((T + pt, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bt, 1), jnp.float32)] * 3,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(h, w, targets)
-    return loss[:T]
+    )(h, w, targets[:, None])
+    return loss[:T, 0]

@@ -12,13 +12,18 @@ per-iteration time printed side by side:
     PYTHONPATH=src python -m repro.launch.train --mode ps \
         --algorithm hogwild_easgd --transport thread --ps-workers 4
 
-On this CPU container use --reduced; on a real cluster drop it and point
---mesh at the production topology.
+``--reduced`` trains the architecture's small CPU-test config; without it
+the published widths are used. The sync mode spreads over every visible
+device: ``--n-pods`` EASGD workers, each with ``devices / n_pods`` of them.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
+import dataclasses
+import os
 import time
+from typing import Any
 
 import jax
 import numpy as np
@@ -29,8 +34,130 @@ from repro.core.easgd import EASGDConfig
 from repro.core.elastic import ElasticConfig
 from repro.data import ShardedPipeline, SyntheticLMStream
 from repro.ft import Watchdog
-from repro.launch.mesh import make_host_mesh, n_pods_of
+from repro.launch.mesh import make_host_mesh
+from repro.models.common import ModelConfig
+from repro.runtime import sharding as shd
 from repro.runtime.train import build_train_step
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and
+    nothing else is set. Otherwise the cache lives at ``<checkout>/
+    .jax_cache``: a fixed path, since the path is part of the cache key."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+@contextlib.contextmanager
+def count_compiles(fun_name: str):
+    """Yields ``[n, seconds]``: the backend compiles (or persistent-cache
+    loads) of the jitted function ``fun_name`` while the block runs."""
+    seen = [0, 0.0]
+    tag = f"jit({fun_name})"
+
+    def on_event(event, secs, **kw):
+        if event == _COMPILE_EVENT and kw.get("fun_name") == tag:
+            seen[0] += 1
+            seen[1] += secs
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        yield seen
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+
+
+@dataclasses.dataclass
+class SyncRun:
+    """What one ``run_sync`` call did."""
+    losses: list              # per step, in order
+    step_seconds: list        # wall time per step, to the step's outputs
+    step_compiles: int        # compiles (or cache loads) of the step
+    compile_seconds: float    # their backend time
+    state: Any                # final ElasticState, on the mesh
+    mesh: Any
+    build: Any                # the runtime.train.TrainBuild
+
+
+def run_sync(cfg: ModelConfig, ecfg: ElasticConfig, *, steps: int,
+             batch: int, seq: int, n_pods: int = 1, devices=None,
+             microbatches: int = 1, ckpt_dir: str | None = None,
+             ckpt_every: int = 25, log_every: int = 5) -> SyncRun:
+    """The device Sync-EASGD path: mesh → ``build_train_step`` → data
+    pipeline → step loop (with checkpoint resume and preemption stop).
+
+    ``devices`` (default: all) are split into ``n_pods`` EASGD workers of
+    ``len(devices) // n_pods`` data-parallel devices each; ``batch`` is the
+    global batch in sequences."""
+    devices = list(jax.devices() if devices is None else devices)
+    n_dev = len(devices)
+    if n_pods > 1:
+        mesh = make_host_mesh((n_pods, max(1, n_dev // n_pods), 1),
+                              ("pod", "data", "model"), devices=devices)
+    else:
+        mesh = make_host_mesh((n_dev, 1), ("data", "model"), devices=devices)
+    per_pod = batch // n_pods
+    build = build_train_step(cfg, ecfg, mesh, n_pods=n_pods,
+                             per_pod_batch=per_pod, seq=seq,
+                             microbatches=microbatches)
+    batch_shardings = shd.named(mesh, build.batch_spec_tree)
+    state = build.init_state()
+
+    pipe = ShardedPipeline(
+        lambda shard, n: SyntheticLMStream(cfg.vocab_size, seq, per_pod,
+                                           seed=13, shard=shard, n_shards=n),
+        n_pods=n_pods).start()
+
+    ckpt = CheckpointManager(ckpt_dir) if ckpt_dir else None
+    start_step = 0
+    if ckpt and ckpt.latest_step() is not None:
+        state, meta = ckpt.restore(state)
+        state = jax.device_put(state, shd.named(mesh, build.state_specs))
+        start_step = meta["extra"]["data_step"]
+        pipe.restore(start_step)
+        print(f"resumed from step {start_step}")
+
+    wd = Watchdog().start_heartbeat()
+    t0 = time.time()
+    losses, step_s = [], []
+    step = start_step
+    try:
+        with count_compiles(build.step.__name__) as compiles:
+            for step in range(start_step, steps):
+                if wd.should_stop.is_set():
+                    print("preemption signal — checkpoint + clean exit")
+                    break
+                t_step = time.perf_counter()
+                batch_dev = jax.device_put(pipe.next(), batch_shardings)
+                state, metrics = build.step(state, batch_dev)
+                jax.block_until_ready((state, metrics))
+                step_s.append(time.perf_counter() - t_step)
+                losses.append(float(metrics["loss"]))
+                if step % log_every == 0:
+                    print(f"step {step:5d} loss {losses[-1]:.4f} "
+                          f"acc {float(metrics['accuracy']):.3f} "
+                          f"({time.time()-t0:.1f}s)", flush=True)
+                if ckpt and step and step % ckpt_every == 0:
+                    ckpt.save_async(step, state,
+                                    extra={"data_step": step + 1})
+    finally:
+        pipe.stop()
+        if ckpt:
+            ckpt.wait()
+            ckpt.save(step, state, extra={"data_step": step + 1})
+        wd.close()
+    return SyncRun(losses=losses, step_seconds=step_s,
+                   step_compiles=compiles[0], compile_seconds=compiles[1],
+                   state=state, mesh=mesh, build=build)
 
 
 def run_ps_mode(args) -> list:
@@ -223,15 +350,10 @@ def main(argv=None):
     if args.mode == "ps":
         return run_ps_mode(args)
 
+    use_compile_cache()
     args.schedule = args.schedule or "psum"
     spec = configs.get(args.arch)
     cfg = spec.reduced if args.reduced else spec.config
-    n_dev = jax.device_count()
-    mesh = make_host_mesh(n_data=max(1, n_dev // max(args.n_pods, 1)),
-                          n_model=1,
-                          n_pods=args.n_pods if args.n_pods > 1 else 0)
-    n_pods = n_pods_of(mesh) if args.n_pods > 1 else args.n_pods
-
     ecfg = ElasticConfig(
         easgd=EASGDConfig(eta=args.eta, rho=args.rho, mu=0.9, tau=args.tau),
         schedule=args.schedule,
@@ -242,50 +364,12 @@ def main(argv=None):
     )
     print(f"exchange: schedule={args.schedule} "
           f"compression={args.compression} "
-          f"overlap={not args.no_overlap} n_pods={n_pods}", flush=True)
-    per_pod = args.batch // n_pods
-    build = build_train_step(cfg, ecfg, mesh, n_pods=n_pods,
-                             per_pod_batch=per_pod, seq=args.seq,
-                             microbatches=args.microbatches)
-    state = build.init_state()
-
-    pipe = ShardedPipeline(
-        lambda shard, n: SyntheticLMStream(cfg.vocab_size, args.seq, per_pod,
-                                           seed=13, shard=shard, n_shards=n),
-        n_pods=n_pods).start()
-
-    ckpt = CheckpointManager(args.ckpt_dir) if args.ckpt_dir else None
-    start_step = 0
-    if ckpt and ckpt.latest_step() is not None:
-        state, meta = ckpt.restore(state)
-        start_step = meta["extra"]["data_step"]
-        pipe.restore(start_step)
-        print(f"resumed from step {start_step}")
-
-    wd = Watchdog().start_heartbeat()
-    t0 = time.time()
-    losses = []
-    step = start_step
-    try:
-        for step in range(start_step, args.steps):
-            if wd.should_stop.is_set():
-                print("preemption signal — checkpoint + clean exit")
-                break
-            batch = {k: jax.numpy.asarray(v) for k, v in pipe.next().items()}
-            state, metrics = build.step(state, batch)
-            losses.append(float(metrics["loss"]))
-            if step % args.log_every == 0:
-                print(f"step {step:5d} loss {losses[-1]:.4f} "
-                      f"acc {float(metrics['accuracy']):.3f} "
-                      f"({time.time()-t0:.1f}s)", flush=True)
-            if ckpt and step and step % args.ckpt_every == 0:
-                ckpt.save_async(step, state, extra={"data_step": step + 1})
-    finally:
-        pipe.stop()
-        if ckpt:
-            ckpt.wait()
-            ckpt.save(step, state, extra={"data_step": step + 1})
-        wd.close()
+          f"overlap={not args.no_overlap} n_pods={args.n_pods}", flush=True)
+    run = run_sync(cfg, ecfg, steps=args.steps, batch=args.batch,
+                   seq=args.seq, n_pods=args.n_pods,
+                   microbatches=args.microbatches, ckpt_dir=args.ckpt_dir,
+                   ckpt_every=args.ckpt_every, log_every=args.log_every)
+    losses = run.losses
     if len(losses) > 10:
         first = np.mean(losses[:5])
         last = np.mean(losses[-5:])

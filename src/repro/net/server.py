@@ -84,16 +84,18 @@ def wire_payload_nbytes(n_elements: int, codec: str) -> int:
 def worker_env(pallas: bool = False) -> dict:
     """Environment for a spawned worker interpreter: the repo's src dir on
     PYTHONPATH (shared by the training spawn and the calibration burners —
-    one definition of how a worker process is launched). ``pallas`` pins
-    the XLA CPU backend to a no-FMA ISA BEFORE the child's first jax
-    import, so the fused elastic-update kernel stays bitwise equal to
-    easgd_flat (see kernels/elastic_update.py)."""
+    one definition of how a worker process is launched). A worker never
+    holds an accelerator: a chip belongs to one process, and the parent
+    may be that process, so every child is pinned to the CPU backend.
+    ``pallas`` also pins the XLA CPU backend to a no-FMA ISA BEFORE the
+    child's first jax import, so the fused elastic-update kernel stays
+    bitwise equal to easgd_flat (see kernels/elastic_update.py)."""
     src = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     env = dict(os.environ)
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"
     if pallas:
-        env.setdefault("JAX_PLATFORMS", "cpu")
         env.setdefault("XLA_FLAGS", "--xla_cpu_max_isa=SSE4_2")
     return env
 

@@ -36,6 +36,7 @@ gradient is computed.
 from __future__ import annotations
 
 import argparse
+import functools
 import importlib
 import os
 import socket
@@ -371,15 +372,19 @@ def _p2p_sync_loop(link: Link, mesh: PeerMesh, cfg: dict, grad_fn,
         # first jax import in this (otherwise jax-free) process: pin the
         # CPU backend to a no-FMA ISA so the fused kernel stays BITWISE
         # equal to easgd_flat (XLA contracts a*b+c to fma otherwise);
-        # worker_env ships the same flags, setdefault keeps them
+        # worker_env ships the same flags, setdefault keeps them. A worker
+        # never takes an accelerator: a chip belongs to one process.
         if "jax" not in sys.modules:
-            os.environ.setdefault("JAX_PLATFORMS", "cpu")
+            os.environ["JAX_PLATFORMS"] = "cpu"
             os.environ.setdefault("XLA_FLAGS", "--xla_cpu_max_isa=SSE4_2")
         # importlib: the kernels package re-exports an `elastic_update`
         # FUNCTION that shadows the submodule on attribute-style imports
+        # (f64, CPU-pinned: these kernels run interpreted by design)
         _fk = importlib.import_module("repro.kernels.elastic_update")
-        fused_easgd = _fk.fused_sync_easgd_update
-        fused_sgd = _fk.fused_sync_sgd_update
+        fused_easgd = functools.partial(_fk.fused_sync_easgd_update,
+                                        interpret=True)
+        fused_sgd = functools.partial(_fk.fused_sync_sgd_update,
+                                      interpret=True)
 
     # -- elastic control plane: ONE thread owns the master link's inbound
     # side for the whole run (RECONFIGURE can land at any moment, so the

@@ -155,7 +155,7 @@ def make_jax_mlp(seed: int = 0, n_train: int = 2048, n_test: int = 512,
     import os
     import sys
     if "jax" not in sys.modules:
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
     import jax.numpy as jnp
     from jax import flatten_util

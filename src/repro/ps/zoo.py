@@ -31,7 +31,7 @@ def _gate_cpu():
     import os
     import sys
     if "jax" not in sys.modules:
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        os.environ["JAX_PLATFORMS"] = "cpu"
 
 
 def _layer_sizes(params) -> list[int]:

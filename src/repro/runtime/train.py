@@ -33,7 +33,7 @@ class TrainBuild:
     state_specs: Any          # ElasticState PartitionSpecs
     batch_spec_tree: Any      # batch PartitionSpecs
     abstract_state: Any       # ShapeDtypeStruct ElasticState
-    init_state: Any           # () -> concrete ElasticState (allocates!)
+    init_state: Any           # jitted () -> ElasticState, sharded like step
     param_specs: Any
     n_pods: int
     exchange_plan: Any = None  # repro.comm.ExchangePlan the step executes
@@ -99,7 +99,7 @@ def build_train_step(cfg: ModelConfig, ecfg: ElasticConfig, mesh,
                 params_pod, batch)
         return loss, metrics, grads
 
-    def step(state: ElasticState, batch):
+    def sync_easgd_step(state: ElasticState, batch):
         # per-pod fwd/bwd; intra-pod data-parallel reduction happens via the
         # batch's `data` sharding (GSPMD inserts the gradient all-reduce).
         if microbatches == 1:
@@ -146,12 +146,16 @@ def build_train_step(cfg: ModelConfig, ecfg: ElasticConfig, mesh,
 
     abstract_state = elastic.init_abstract(abstract_p, ecfg, n_pods)
 
+    # built on the mesh with the step's own shardings: each device allocates
+    # only its share, and step 0 sees the layout every later step returns
+    # (an eager init leaves unsharded arrays, so step 1 would compile again)
+    @partial(jax.jit, out_shardings=shd.named(mesh, sspecs))
     def init_state():
         params = init_params(defs, jax.random.PRNGKey(seed), cfg.param_dtype)
         return elastic.init(params, ecfg, n_pods)
 
     jit_step = jax.jit(
-        step,
+        sync_easgd_step,
         in_shardings=(shd.named(mesh, sspecs), shd.named(mesh, bspecs)),
         out_shardings=(shd.named(mesh, sspecs), None),
         donate_argnums=(0,),

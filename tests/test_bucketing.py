@@ -232,13 +232,14 @@ for n in (1188, 4096, 131072, 131072 + 777):
     w_ref, c_ref = w.copy(), c.copy()
     easgd_flat.worker_step("sync_easgd", w_ref, None, g, c_ref, cfg)
     easgd_flat.sync_master_easgd(c_ref, r / P, P, cfg)
-    w_new, c_new = fused_sync_easgd_update(w, g, c, r, P, eta, rho)
+    w_new, c_new = fused_sync_easgd_update(w, g, c, r, P, eta, rho,
+                                           interpret=True)
     assert np.array_equal(w_ref, w_new), ("easgd w", n)
     assert np.array_equal(c_ref, c_new), ("easgd c", n)
     v = rng.standard_normal(n)
     c2_ref, v2_ref = c.copy(), v.copy()
     easgd_flat.sync_master_sgd(c2_ref, v2_ref, r / P, cfg)
-    c2, v2 = fused_sync_sgd_update(c, v, r, P, eta, mu)
+    c2, v2 = fused_sync_sgd_update(c, v, r, P, eta, mu, interpret=True)
     assert np.array_equal(c2_ref, c2), ("sgd c", n)
     assert np.array_equal(v2_ref, v2), ("sgd v", n)
 print("BITWISE-OK")

@@ -23,8 +23,8 @@ def test_every_schedule_equals_psum(subproc):
         import warnings; warnings.filterwarnings('ignore')
         import jax, jax.numpy as jnp, numpy as np
         from repro import comm
-        from repro.utils.jaxcompat import auto_mesh
-        mesh = auto_mesh((8,), ('x',))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh((8,), ('x',))
         x = jnp.arange(96, dtype=jnp.float32) * 0.125 - 3.0
         want = np.asarray(x) * 8
         for algo in comm.names():
@@ -47,12 +47,12 @@ def test_exchange_plan_runs_every_schedule(subproc):
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro import comm
-        from repro.utils.jaxcompat import auto_mesh, shard_map
-        mesh = auto_mesh((4,), ('pod',))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh((4,), ('pod',))
         vals = jnp.stack([jnp.full((6,), float(i)) for i in range(4)])
         for name in comm.names():
             plan = comm.make_plan(name, axis_name='pod', n_total=4)
-            @partial(shard_map, mesh=mesh, in_specs=P('pod'),
+            @partial(jax.shard_map, mesh=mesh, in_specs=P('pod'),
                      out_specs=P('pod'), check_vma=False)
             def f(x):
                 tree = {'w': x[0]}
@@ -205,8 +205,8 @@ def test_auto_schedule_builds_train_step(subproc):
         from repro.core.easgd import EASGDConfig
         from repro.core.elastic import ElasticConfig
         from repro.runtime.train import build_train_step
-        from repro.utils.jaxcompat import auto_mesh
-        mesh = auto_mesh((2, 2, 2), ('pod', 'data', 'model'))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh((2, 2, 2), ('pod', 'data', 'model'))
         cfg = configs.get('gemma3-4b').reduced
         build = build_train_step(
             cfg, ElasticConfig(easgd=EASGDConfig(), schedule='auto'), mesh,

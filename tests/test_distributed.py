@@ -9,8 +9,8 @@ def test_collective_schedules_equal_psum(subproc):
         import warnings; warnings.filterwarnings('ignore')
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import collectives
-        from repro.utils.jaxcompat import auto_mesh
-        mesh = auto_mesh((8,), ('x',))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh((8,), ('x',))
         x = jnp.arange(64, dtype=jnp.float32) * 0.25 - 3.0
         for algo in ['psum', 'butterfly', 'ring', 'round_robin']:
             out = collectives.shard_map_allreduce(mesh, x, 'x', algo)
@@ -27,9 +27,9 @@ def test_hierarchical_allreduce(subproc):
         from functools import partial
         from jax.sharding import PartitionSpec as P
         from repro.core import collectives
-        from repro.utils.jaxcompat import auto_mesh, shard_map
-        mesh = auto_mesh((2, 4), ('pod', 'data'))
-        @partial(shard_map, mesh=mesh, in_specs=P(('pod', 'data')),
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh((2, 4), ('pod', 'data'))
+        @partial(jax.shard_map, mesh=mesh, in_specs=P(('pod', 'data')),
                  out_specs=P(('pod', 'data')), check_vma=False)
         def f(x):
             # local shard is this device's 16-element row
@@ -56,8 +56,8 @@ def test_multipod_train_step_matches_reference(subproc):
         from repro.models import transformer as tfm
         from repro.models.common import init_params
 
-        from repro.utils.jaxcompat import auto_mesh
-        mesh = auto_mesh((2, 2, 2), ('pod', 'data', 'model'))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh((2, 2, 2), ('pod', 'data', 'model'))
         cfg = configs.get('gemma3-4b').reduced
         ecfg = ElasticConfig(easgd=EASGDConfig(eta=0.05, rho=0.02, mu=0.9),
                              packed=True)
@@ -99,8 +99,8 @@ def test_sharded_serve_matches_reference(subproc):
         from repro.models import transformer as tfm
         from repro.models.common import init_params
 
-        from repro.utils.jaxcompat import auto_mesh
-        mesh = auto_mesh((4, 2), ('data', 'model'))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh((4, 2), ('data', 'model'))
         cfg = dataclasses.replace(configs.get('deepseek-v2-236b').reduced,
                                   compute_dtype=jnp.float32)
         B, L = 8, 32
@@ -138,8 +138,8 @@ def test_dryrun_smoke_reduced_mesh(subproc):
         from repro.runtime.serve import build_serve_steps
         from repro.launch import hloparse
 
-        from repro.utils.jaxcompat import auto_mesh
-        mesh = auto_mesh((2, 2, 2), ('pod', 'data', 'model'))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh((2, 2, 2), ('pod', 'data', 'model'))
         for aid in ['recurrentgemma-2b', 'grok-1-314b']:
             cfg = configs.get(aid).reduced
             build = build_train_step(
@@ -156,8 +156,8 @@ def test_dryrun_smoke_reduced_mesh(subproc):
                   pc.collective_bytes)
 
         cfg = configs.get('mamba2-780m').reduced
-        from repro.utils.jaxcompat import auto_mesh
-        mesh2 = auto_mesh((4, 2), ('data', 'model'))
+        from repro.launch.mesh import make_host_mesh
+        mesh2 = make_host_mesh((4, 2), ('data', 'model'))
         sb = build_serve_steps(cfg, mesh2, batch=8, max_len=64)
         tok = jax.ShapeDtypeStruct((8, 1), jnp.int32)
         pos = jax.ShapeDtypeStruct((8,), jnp.int32)

@@ -25,7 +25,7 @@ from repro.core import (
     elastic_apply_gradients, elastic_init,
 )
 from repro.core import compression, easgd
-from repro.utils.jaxcompat import auto_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.core.elastic import n_pods_of
 
 
@@ -147,7 +147,7 @@ def test_packed_unpacked_equivalence(compression_name):
     state = elastic_init(params, cfg_u, n_pods=2)
     grads = jax.tree_util.tree_map(
         lambda x: jnp.full_like(x, 0.2).at[0].set(-0.1), state.params)
-    mesh = auto_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh((1, 1), ("data", "model"))
     from jax.sharding import PartitionSpec as P
     pspecs = {"w": P(), "b": P()}
     out_u = elastic_apply_gradients(state, grads, cfg_u)
@@ -191,7 +191,7 @@ def test_sign_ef_error_feedback_converges():
     state = elastic_init(params, cfg, n_pods=2)
     # workers pinned apart by antisymmetric gradients; center should stay ~0
     grads = {"w": jnp.stack([jnp.ones(16), -jnp.ones(16)])}
-    mesh = auto_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh((1, 1), ("data", "model"))
     from jax.sharding import PartitionSpec as P
     for _ in range(10):
         state = elastic_apply_gradients(state, grads, cfg, mesh=mesh,

@@ -69,9 +69,9 @@ def test_collectives_counted_with_trips(subproc):
         from jax.sharding import PartitionSpec as P
         from repro.launch import hloparse
         import numpy as np
-        from repro.utils.jaxcompat import auto_mesh, shard_map
-        mesh = auto_mesh((8,), ('x',))
-        @partial(shard_map, mesh=mesh, in_specs=P('x'), out_specs=P('x'),
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh((8,), ('x',))
+        @partial(jax.shard_map, mesh=mesh, in_specs=P('x'), out_specs=P('x'),
                  check_vma=False)
         def body(x):
             def step(c, _):
@@ -101,11 +101,11 @@ def test_sign_ef_collective_bytes_by_dtype(subproc):
         from repro.launch import hloparse
         from repro import comm
         from repro.core import compression
-        from repro.utils.jaxcompat import auto_mesh, shard_map
-        mesh = auto_mesh((4,), ('pod',))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh((4,), ('pod',))
         plan = comm.make_plan('psum', 'sign_ef', n_total=4, axis_name='pod')
         n = 4096
-        @partial(shard_map, mesh=mesh, in_specs=(P('pod'), P('pod')),
+        @partial(jax.shard_map, mesh=mesh, in_specs=(P('pod'), P('pod')),
                  out_specs=P('pod'), check_vma=False)
         def body(delta, ef):
             mean, _ = plan.reduce_mean_flat(delta, ef)

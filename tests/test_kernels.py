@@ -58,6 +58,7 @@ def test_flash_attention_vs_dense():
     (1 << 12, 1024, jnp.float32),
     (1 << 14, 4096, jnp.float32),
     (1 << 12, 4096, jnp.bfloat16),
+    ((1 << 12) + 5, 4096, jnp.float32),   # unaligned: padded, cut back
 ])
 def test_elastic_update_kernel(n, block, dtype):
     ks = jax.random.split(jax.random.PRNGKey(2), 5)
