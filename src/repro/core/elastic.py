@@ -278,35 +278,42 @@ def _exchange_packed(state, grads, cfg, mesh, param_specs, pod_axis,
     def body(step, params, momentum, center, ef, g):
         # local shards; pod-dim is size n_pods/|pod axis| locally (=1 on the
         # production mesh). The pod dim stays outer in the packed buffers.
+        # The four phases carry named scopes (elastic.pack, .exchange,
+        # .update, .unpack), so a profiler trace can split the update's
+        # flat copies from its arithmetic.
         local_pods = jax.tree_util.tree_leaves(params)[0].shape[0]
-        w2 = _pack_local(params, local_pods)      # (local_pods, n_local)
-        g2 = _pack_local(g, local_pods)
-        v2 = _pack_local(momentum, local_pods)
-        c2 = _pack_local(center)[None]            # (1, n_local)
+        with jax.named_scope("elastic.pack"):
+            w2 = _pack_local(params, local_pods)  # (local_pods, n_local)
+            g2 = _pack_local(g, local_pods)
+            v2 = _pack_local(momentum, local_pods)
+            c2 = _pack_local(center)[None]        # (1, n_local)
 
         # --- the ONE cross-pod collective (plan = schedule × compression) --
-        delta = (w2 - c2)
-        if cfg.compression != "none":
-            ef_flat = _pack_local(ef, local_pods)
-            mean_delta, ef_new2 = plan.reduce_mean_flat(delta, ef_flat)
-            ef_new = _unpack_local(ef_new2, ef, local_pods)
-        else:
-            mean_delta, _ = plan.reduce_mean_flat(delta)
-            ef_new = ef
-        mean_w = c2[0] + mean_delta
+        with jax.named_scope("elastic.exchange"):
+            delta = (w2 - c2)
+            if cfg.compression != "none":
+                ef_flat = _pack_local(ef, local_pods)
+                mean_delta, ef_new2 = plan.reduce_mean_flat(delta, ef_flat)
+                ef_new = _unpack_local(ef_new2, ef, local_pods)
+            else:
+                mean_delta, _ = plan.reduce_mean_flat(delta)
+                ef_new = ef
+            mean_w = c2[0] + mean_delta
 
         # --- fused elementwise update (eqs 5–6 + 2) ------------------------
-        v_new = e.mu * v2 - e.eta * g2
-        w_new = w2 + v_new - e.eta * e.rho * (w2 - c2)
-        c_new = c2[0] + e.alpha * n_pods * (mean_w - c2[0])
+        with jax.named_scope("elastic.update"):
+            v_new = e.mu * v2 - e.eta * g2
+            w_new = w2 + v_new - e.eta * e.rho * (w2 - c2)
+            c_new = c2[0] + e.alpha * n_pods * (mean_w - c2[0])
 
-        return (
-            step + 1,
-            _unpack_local(w_new, params, local_pods),
-            _unpack_local(v_new, momentum, local_pods),
-            _unpack_local(c_new, center),
-            ef_new,
-        )
+        with jax.named_scope("elastic.unpack"):
+            return (
+                step + 1,
+                _unpack_local(w_new, params, local_pods),
+                _unpack_local(v_new, momentum, local_pods),
+                _unpack_local(c_new, center),
+                ef_new,
+            )
 
     in_specs = (P(), specs.params, specs.momentum, specs.center,
                 specs.ef_error if cfg.compression != "none" else P(),

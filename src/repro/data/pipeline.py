@@ -10,6 +10,12 @@ disjoint deterministic shard of the stream, keyed by (seed, step, pod), so
 
 A background thread prefetches ``depth`` batches ahead (the paper's
 'asynchronously copies b samples' — overlap of data movement with compute).
+
+Two host spans go into the profiler's trace, on the device trace's clock:
+``repro.input.produce`` around each batch's generation (on the producer
+thread, or on the caller's when it regenerates), and ``repro.input.wait``
+around a ``next()`` that found the prefetch queue empty and blocked. JAX is
+imported on construction, so importing this module stays numpy-only.
 """
 from __future__ import annotations
 
@@ -42,13 +48,16 @@ class ShardedPipeline:
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self._next_produce = start_step
+        from jax.profiler import TraceAnnotation
+        self._span = TraceAnnotation
 
     def _produce(self, step: int):
-        shards = [s.batch_at(step) for s in self.sources]
-        return {
-            k: np.stack([sh[k] for sh in shards], axis=0)
-            for k in shards[0]
-        }
+        with self._span("repro.input.produce"):
+            shards = [s.batch_at(step) for s in self.sources]
+            return {
+                k: np.stack([sh[k] for sh in shards], axis=0)
+                for k in shards[0]
+            }
 
     def _worker(self):
         while not self._stop.is_set():
@@ -74,7 +83,11 @@ class ShardedPipeline:
             batch = self._produce(self.state.step)
             self.state.step += 1
             return batch
-        step, batch = self._q.get()
+        try:
+            step, batch = self._q.get_nowait()
+        except queue.Empty:
+            with self._span("repro.input.wait"):
+                step, batch = self._q.get()
         # if a restore rewound the cursor, regenerate deterministically
         if step != self.state.step:
             batch = self._produce(self.state.step)

@@ -137,9 +137,10 @@ def run_sync(cfg: ModelConfig, ecfg: ElasticConfig, *, steps: int,
                     print("preemption signal — checkpoint + clean exit")
                     break
                 t_step = time.perf_counter()
-                batch_dev = jax.device_put(pipe.next(), batch_shardings)
-                state, metrics = build.step(state, batch_dev)
-                jax.block_until_ready((state, metrics))
+                with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                    batch_dev = jax.device_put(pipe.next(), batch_shardings)
+                    state, metrics = build.step(state, batch_dev)
+                    jax.block_until_ready((state, metrics))
                 step_s.append(time.perf_counter() - t_step)
                 losses.append(float(metrics["loss"]))
                 if step % log_every == 0:

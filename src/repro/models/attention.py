@@ -405,6 +405,7 @@ def _project_qkv(cfg: ModelConfig, p, x, positions, *, theta,
     return q, k, v
 
 
+@jax.named_scope("attention")
 def attention_block(cfg: ModelConfig, p, x, positions, *, kind="attn",
                     cache=None, cache_pos=None, mrope_positions=None):
     """One attention block.

@@ -1,6 +1,7 @@
 """Dense gated FFN (SwiGLU / GeGLU)."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.models import sctx
@@ -16,6 +17,7 @@ def ffn_defs(cfg: ModelConfig, d_ff: int | None = None) -> dict:
     }
 
 
+@jax.named_scope("mlp")
 def ffn_block(cfg: ModelConfig, p, x):
     cd = cfg.compute_dtype
     act = act_fn(cfg.act)

@@ -299,6 +299,7 @@ def lm_loss(cfg: ModelConfig, params, batch, extra_fwd_kwargs=None):
     Cs = _divisor_chunk(S, max(1, cfg.loss_chunk // B))
     nc = S // Cs
 
+    @jax.named_scope("loss")
     def chunk_fn(carry, inp):
         h_c, t_c, m_c = inp                     # (B,Cs,d), (B,Cs), (B,Cs)
         logits = jnp.einsum("bcd,dv->bcv", h_c, w.astype(h_c.dtype),

@@ -62,3 +62,33 @@ def test_run_sync_compiles_step_once(subproc, n_pods, n_devices):
         print('RUN_SYNC_OK', run.losses)
     """, n_devices=n_devices, timeout=600)
     assert "RUN_SYNC_OK" in out
+
+
+def test_run_sync_steps_on_the_profiler_trace(subproc, tmp_path):
+    """Under ``jax.profiler.trace``, each step of ``run_sync`` is a "train"
+    step event with its number, and the pipeline's spans are there too."""
+    out = subproc(f"""
+        import glob
+        import warnings; warnings.filterwarnings('ignore')
+        import jax
+        from jax.profiler import ProfileData
+        from repro import configs
+        from repro.core.easgd import EASGDConfig
+        from repro.core.elastic import ElasticConfig
+        from repro.launch.train import run_sync
+
+        cfg = configs.get('phi3-mini-3.8b').reduced
+        ecfg = ElasticConfig(easgd=EASGDConfig(eta=0.02, rho=0.01, mu=0.9))
+        with jax.profiler.trace({str(tmp_path)!r}):
+            run_sync(cfg, ecfg, steps=3, batch=2, seq=16, log_every=10)
+        path = glob.glob({str(tmp_path)!r} + '/**/*.xplane.pb',
+                         recursive=True)[0]
+        events = [e for plane in ProfileData.from_file(path).planes
+                  for line in plane.lines for e in line.events]
+        steps = sorted(dict(e.stats)['step_num'] for e in events
+                       if e.name == 'train')
+        assert steps == [0, 1, 2], steps
+        assert any(e.name == 'repro.input.produce' for e in events)
+        print('STEP_EVENTS_OK')
+    """, n_devices=1, timeout=600)
+    assert "STEP_EVENTS_OK" in out

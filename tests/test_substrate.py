@@ -62,6 +62,45 @@ def test_pipeline_prefetch_matches_sync():
         np.testing.assert_array_equal(x["tokens"], y["tokens"])
 
 
+def test_pipeline_spans_on_the_profiler_trace(tmp_path):
+    """``repro.input.produce`` around each batch's generation on the
+    producer thread; ``repro.input.wait`` only around a ``next()`` that
+    found the prefetch queue empty."""
+    import glob
+    import time
+
+    from jax.profiler import ProfileData
+
+    class Slow:
+        def __init__(self, shard, n_shards):
+            pass
+
+        def batch_at(self, step):
+            time.sleep(0.05)
+            return {"tokens": np.full((2, 4), step)}
+
+    pipe = ShardedPipeline(Slow, n_pods=2)
+    with jax.profiler.trace(str(tmp_path)):
+        pipe.start()
+        first = pipe.next()          # nothing made yet: waits
+        deadline = time.time() + 10
+        while pipe._q.qsize() < pipe.depth and time.time() < deadline:
+            time.sleep(0.01)
+        later = [pipe.next() for _ in range(pipe.depth)]   # queue full
+        pipe.stop()
+    assert [b["tokens"][0, 0, 0] for b in [first] + later] == [0, 1, 2]
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = [(e.name, e.duration_ns) for plane in
+              ProfileData.from_file(path[0]).planes for line in plane.lines
+              for e in line.events if e.name.startswith("repro.")]
+    names = [n for n, _ in events]
+    assert names.count("repro.input.wait") == 1, names
+    assert names.count("repro.input.produce") >= 3, names
+    # each produce span holds two 50 ms shards
+    assert all(d >= 0.1e9 for n, d in events
+               if n == "repro.input.produce"), events
+
+
 def test_pipeline_shards_disjoint():
     p = ShardedPipeline(_factory, n_pods=2)
     b = p.next()
