@@ -2,7 +2,8 @@
 + sliding-window + decode-with-cache.
 
 The blocked implementation is the production CPU/dry-run path AND the oracle
-for the Pallas kernel (kernels/flash_attention.py). It never materializes the
+for the Pallas kernels (kernels/flash_attention.py, and the splash kernel the
+training path runs when lowered for a TPU). It never materializes the
 full (Sq × Skv) score matrix: an outer scan over query blocks and an inner
 online-softmax scan over KV blocks keep the working set at
 (q_block × kv_block) per head — the same tiling the TPU kernel uses in VMEM.
@@ -15,6 +16,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
 from repro.models import sctx
 from repro.models.common import ModelConfig, ParamDef, rms_norm, softcap
@@ -73,7 +75,7 @@ def apply_mrope(x, positions, theta: float, sections):
 #
 # Two paths:
 #  * autodiff path (kv_valid_len / softcap support) — serving only;
-#  * custom-VJP path (training default): the backward recomputes score
+#  * custom-VJP path (training, off the TPU): the backward recomputes score
 #    tiles from (q, k, v, out, lse) — flash-attention backward — instead of
 #    saving the online-softmax carries of every KV step, which costs
 #    O(S·D·n_kv_blocks) residual memory under scan autodiff.
@@ -303,12 +305,10 @@ def _flash_vjp_bwd(causal, window, qb, kb, res, dout):
 _flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-def flash_attention_train(q, k, v, *, causal=True, window=0, q_block=512,
-                          kv_block=1024):
-    """Training-path attention with the manual flash backward. Pads to
-    block multiples; no kv_valid_len/softcap (serving uses the autodiff
-    path)."""
-    B, Sq, H, D = q.shape
+def _flash_blocked(q, k, v, *, causal, window, q_block, kv_block):
+    """The custom-VJP path: pads to block multiples; no kv_valid_len/softcap
+    (serving uses the autodiff path)."""
+    Sq = q.shape[1]
     Skv = k.shape[1]
     qb = min(q_block, Sq)
     kb = min(kv_block, Skv)
@@ -326,6 +326,117 @@ def flash_attention_train(q, k, v, *, causal=True, window=0, q_block=512,
                              "when padding KV")
     out = _flash_attention(q, k, v, causal, window, qb, kb)
     return out[:, :Sq]
+
+
+# ---------------------------------------------------------------------------
+# Pallas flash kernel (TPU): the shipped splash attention, forward and fused
+# backward. Score tiles stay in VMEM, and tiles the causal (or window) mask
+# empties are skipped.
+# ---------------------------------------------------------------------------
+
+LANES = 128            # the kernel's tiles are whole multiples of this
+# tiles for configurations whose attn_q_block / attn_kv_block the kernel
+# refuses (not multiples of LANES); from an on-chip sweep (PERF.md)
+SPLASH_Q_BLOCK = 1024
+SPLASH_KV_BLOCK = 1024
+# above this head dim the fused backward's tiles outgrow VMEM (512 fails at
+# 1024 x 1024 tiles, AOT for a v5e); no registered config has one
+MAX_HEAD_DIM = 256
+
+
+def _splash_tiles(S, q_block, kv_block):
+    """(padded length, q tile, kv tile): tiles that are multiples of LANES
+    and no longer than the sequence rounded up to LANES; the length is
+    padded to a multiple of both."""
+    s_lanes = -(-S // LANES) * LANES
+    bq = q_block if q_block % LANES == 0 else SPLASH_Q_BLOCK
+    bkv = kv_block if kv_block % LANES == 0 else SPLASH_KV_BLOCK
+    bq, bkv = min(bq, s_lanes), min(bkv, s_lanes)
+    tile = math.lcm(bq, bkv)
+    return -(-S // tile) * tile, bq, bkv
+
+
+def _splash_kernel(S, heads, window, bq, bkv, interpret):
+    """The kernel for ``heads`` query heads of padded length ``S`` (splash
+    caches the mask's block tables; its arrays belong to the trace)."""
+    if window:
+        mask = splash.LocalMask((S, S), window_size=(window - 1, 0),
+                                offset=0)
+    else:
+        mask = splash.CausalMask((S, S))
+    blocks = splash.BlockSizes(
+        block_q=bq, block_kv=bkv, block_kv_compute=bkv,
+        block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bkv,
+        use_fused_bwd_kernel=True)
+    return splash.make_splash_mha(
+        splash.MultiHeadMask([mask] * heads), block_sizes=blocks,
+        head_shards=1, q_seq_shards=1, interpret=interpret)
+
+
+def _kernel_placement(q, k, v, causal):
+    """Where the kernel runs these shapes: (mesh, q spec, k/v spec) on the
+    installed mesh, (None, None, None) with none installed, or None where
+    the kernel refuses them. It takes causal self-attention with one head
+    dim, at most MAX_HEAD_DIM, for q, k and v, whose query and kv heads
+    split alike over the mesh (whole kv groups per device)."""
+    S, D = q.shape[1], q.shape[-1]
+    if not causal or k.shape[1] != S or k.shape[-1] != D \
+            or v.shape[-1] != D or D > MAX_HEAD_DIM:
+        return None
+    lay = sctx.layout()
+    if lay is None:
+        return None, None, None
+    q_spec = lay.spec(q.shape, ("batch", "seq", "heads", "head_dim"))
+    kv_spec = lay.spec(k.shape, ("batch", "seq", "kv_heads", "head_dim"))
+    if q_spec[2] != kv_spec[2]:
+        return None
+    return lay.mesh, q_spec, kv_spec
+
+
+def _flash_kernel(q, k, v, *, window, q_block, kv_block, placement,
+                  interpret=False):
+    """Causal attention through the Pallas kernel, per device: under
+    ``jax.shard_map`` over the installed mesh (batch over data, heads over
+    model), so the partitioner never sees the kernel. q is scaled before
+    the kernel (which applies no 1/sqrt(D)); inputs stay in their dtype."""
+    S, D = q.shape[1], q.shape[-1]
+    Sp, bq, bkv = _splash_tiles(S, q_block, kv_block)
+    q = q * jnp.asarray(1.0 / math.sqrt(D), q.dtype)
+    if Sp != S:
+        pad = ((0, 0), (0, Sp - S), (0, 0), (0, 0))
+        q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
+
+    def local(q, k, v):
+        kernel = _splash_kernel(Sp, q.shape[2], window, bq, bkv, interpret)
+        heads_major = lambda t: t.transpose(0, 2, 1, 3)
+        out = jax.vmap(kernel)(heads_major(q), heads_major(k),
+                               heads_major(v))
+        return heads_major(out)
+
+    mesh, q_spec, kv_spec = placement
+    with jax.named_scope("attention.flash"):
+        if mesh is None:
+            out = local(q, k, v)
+        else:
+            out = jax.shard_map(local, mesh=mesh,
+                                in_specs=(q_spec, kv_spec, kv_spec),
+                                out_specs=q_spec, check_vma=False)(q, k, v)
+    return out[:, :S]
+
+
+def flash_attention_train(q, k, v, *, causal=True, window=0, q_block=512,
+                          kv_block=1024):
+    """Training-path attention. Lowered for a TPU, the shapes the Pallas
+    kernel takes (``_kernel_placement``) run it; everything else, and
+    every other platform, runs the blocked custom-VJP path."""
+    blocked = partial(_flash_blocked, causal=causal, window=window,
+                      q_block=q_block, kv_block=kv_block)
+    placement = _kernel_placement(q, k, v, causal)
+    if placement is None:
+        return blocked(q, k, v)
+    kernel = partial(_flash_kernel, window=window, q_block=q_block,
+                     kv_block=kv_block, placement=placement)
+    return lax.platform_dependent(q, k, v, tpu=kernel, default=blocked)
 
 
 def decode_attention(q, k_cache, v_cache, valid_mask, cap=0.0):

@@ -49,7 +49,7 @@ def build_serve_steps(cfg: ModelConfig, mesh, *, batch: int, max_len: int):
     named = lambda t: shd.named(mesh, t)
     constrain = shd.block_constrainer(cfg, mesh)
 
-    act_fn = shd.activation_constrainer(cfg, mesh)
+    act_spec = shd.activation_spec(cfg, mesh)
 
     def _extra_specs(S):
         b_ax = tok_spec[0]
@@ -67,12 +67,12 @@ def build_serve_steps(cfg: ModelConfig, mesh, *, batch: int, max_len: int):
             lambda s: jnp.zeros(s.shape, s.dtype),
             tfm.init_cache_defs(cfg, batch, max_len))
         caches = jax.lax.with_sharding_constraint(caches, named(cspecs))
-        with sctx.use(act_fn):
+        with sctx.use(mesh, act_spec):
             return tfm.prefill(cfg, params, tokens, caches,
                                constrain=constrain, **extras)
 
     def decode_fn(params, caches, token, pos, extras):
-        with sctx.use(act_fn):
+        with sctx.use(mesh, act_spec):
             return tfm.decode_step(cfg, params, token, caches, pos,
                                    constrain=constrain, **extras)
 

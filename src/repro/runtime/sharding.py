@@ -125,8 +125,8 @@ def named(mesh, spec_tree):
     )
 
 
-def activation_constrainer(cfg: ModelConfig, mesh):
-    """Build the models.sctx constraint fn: logical activation axes →
+def activation_spec(cfg: ModelConfig, mesh):
+    """Build the models.sctx spec fn: logical activation axes →
     PartitionSpec on this mesh. batch/groups→data, heads/ff/vocab/inner→
     model, experts_dp→data (EP buffers; takes priority over groups so the
     dispatch buffer resharding is the token all-to-all). Dims that don't
@@ -138,7 +138,7 @@ def activation_constrainer(cfg: ModelConfig, mesh):
     model_axes = {"heads": 1, "kv_heads": 1, "ff": 1, "vocab": 1,
                   "experts": 1, "inner": 1}
 
-    def fn(x, logical):
+    def spec(shape, logical):
         axes = [None] * len(logical)
         used = set()
         order = sorted(
@@ -146,7 +146,7 @@ def activation_constrainer(cfg: ModelConfig, mesh):
             key=lambda i: data_axes.get(logical[i],
                                         model_axes.get(logical[i], 9)))
         for i in order:
-            dim, name = x.shape[i], logical[i]
+            dim, name = shape[i], logical[i]
             if name in data_axes and "data" not in used and dim % dsz == 0:
                 axes[i] = "data"
                 used.add("data")
@@ -154,10 +154,9 @@ def activation_constrainer(cfg: ModelConfig, mesh):
                     and dim % msz == 0:
                 axes[i] = "model"
                 used.add("model")
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, P(*axes)))
+        return P(*axes)
 
-    return fn
+    return spec
 
 
 def block_constrainer(cfg: ModelConfig, mesh):

@@ -91,10 +91,10 @@ def build_train_step(cfg: ModelConfig, ecfg: ElasticConfig, mesh,
     loss_fn = _per_pod_loss(cfg, shd.block_constrainer(cfg, mesh))
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
     vmap_kw = {"spmd_axis_name": pod_axis} if pod_axis else {}
-    act_fn = shd.activation_constrainer(cfg, mesh)
+    act_spec = shd.activation_spec(cfg, mesh)
 
     def grads_of(params_pod, batch):
-        with sctx.use(act_fn):
+        with sctx.use(mesh, act_spec):
             (loss, metrics), grads = jax.vmap(grad_fn, **vmap_kw)(
                 params_pod, batch)
         return loss, metrics, grads

@@ -125,3 +125,153 @@ def test_flash_train_custom_vjp_matches_autodiff():
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4)
+
+
+def _kernel_vs_blocked(q, k, v, dout, window, q_block, kv_block):
+    """(out, dq, dk, dv) of the Pallas kernel path (interpreted) and of the
+    blocked custom-VJP path, as float32 numpy."""
+    from repro.models import attention as A
+
+    kw = dict(window=window, q_block=q_block, kv_block=kv_block)
+    placement = A._kernel_placement(q, k, v, True)
+    assert placement is not None
+
+    def kernel(q, k, v):
+        return A._flash_kernel(q, k, v, placement=placement,
+                               interpret=True, **kw)
+
+    def blocked(q, k, v):
+        return A._flash_blocked(q, k, v, causal=True, **kw)
+
+    def run(f):
+        out, vjp = jax.vjp(f, q, k, v)
+        return [np.asarray(x, np.float32) for x in (out, *vjp(dout))]
+
+    return run(kernel), run(blocked)
+
+
+@pytest.mark.parametrize(
+    "B,S,H,KVH,D,window,q_block,kv_block",
+    [
+        (2, 256, 4, 4, 96, 0, 128, 128),     # phi3's head_dim, H == KVH
+        (2, 256, 4, 2, 64, 0, 128, 256),     # GQA: 2 query heads per kv head
+        (1, 200, 4, 2, 96, 0, 512, 1024),    # padded up to one 256 tile
+        (1, 256, 4, 2, 64, 37, 128, 128),    # odd sliding window
+        (1, 300, 2, 1, 64, 0, 96, 48),       # blocks the kernel refuses
+    ],
+)
+def test_flash_kernel_matches_blocked_path(B, S, H, KVH, D, window, q_block,
+                                           kv_block):
+    """The training path's Pallas kernel (what a TPU lowering runs) against
+    the blocked path it replaces: output and (dq, dk, dv), bfloat16 inputs,
+    within bfloat16 rounding of each array's scale."""
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    q = jax.random.normal(ks[0], (B, S, H, D), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (B, S, KVH, D), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (B, S, KVH, D), jnp.bfloat16)
+    dout = jax.random.normal(ks[3], (B, S, H, D), jnp.bfloat16)
+    got, want = _kernel_vs_blocked(q, k, v, dout, window, q_block, kv_block)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        scale = np.abs(b).max()
+        np.testing.assert_allclose(a, b, rtol=2e-2, atol=2e-2 * scale,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("causal,Skv,D,Dv", [
+    (False, 256, 64, 64),     # full attention
+    (True, 128, 64, 64),      # cross-length
+    (True, 256, 192, 128),    # MLA: Dv != D
+    (True, 256, 512, 512),    # past MAX_HEAD_DIM
+])
+def test_flash_kernel_refuses_shapes(causal, Skv, D, Dv):
+    """Shapes the kernel refuses keep the blocked path on every platform."""
+    from repro.models import attention as A
+
+    sd = jax.ShapeDtypeStruct
+    q = sd((1, 256, 4, D), jnp.bfloat16)
+    k = sd((1, Skv, 4, D), jnp.bfloat16)
+    v = sd((1, Skv, 4, Dv), jnp.bfloat16)
+    assert A._kernel_placement(q, k, v, causal) is None
+
+
+def _strip_hlo(text: str) -> list:
+    """A compiled module's instructions without metadata, source locations
+    and instruction numbering."""
+    import re
+    lines = text.splitlines()
+    start = next(i for i, l in enumerate(lines) if re.match(r"(%|ENTRY)", l))
+    out = []
+    for line in lines[start:]:
+        line = re.sub(r", metadata=\{[^}]*\}", "", line)
+        out.append(re.sub(r"(%[\w\-]+)\.\d+", r"\1", line))
+    return out
+
+
+def test_flash_train_cpu_lowering_is_the_blocked_path():
+    """Lowered for the CPU, flash_attention_train holds no Mosaic custom
+    call, and its compiled gradient is the blocked path's, op for op."""
+    from repro.models import attention as A
+
+    q = jax.ShapeDtypeStruct((2, 256, 4, 64), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((2, 256, 2, 64), jnp.bfloat16)
+    kw = dict(causal=True, window=0, q_block=128, kv_block=128)
+
+    def compiled(attn):
+        loss = lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) ** 2)
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, kv, kv).compile().as_text()
+
+    train = compiled(lambda q, k, v: A.flash_attention_train(q, k, v, **kw))
+    assert "tpu_custom_call" not in train
+    assert _strip_hlo(train) == _strip_hlo(
+        compiled(lambda q, k, v: A._flash_blocked(q, k, v, **kw)))
+
+
+def test_flash_kernel_per_device_under_pod_vmap(subproc):
+    """Under the train step's vmap over pods, the kernel runs per device in
+    a shard_map (heads split over model), with no gather of q, k or v, and
+    matches the blocked path."""
+    out = subproc("""
+        from functools import partial
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_host_mesh
+        from repro.models import attention as A, sctx
+        from repro.runtime.sharding import activation_spec
+        mesh = make_host_mesh((2, 1, 2), ("pod", "data", "model"))
+        ks = jax.random.split(jax.random.PRNGKey(0), 4)
+        q = jax.random.normal(ks[0], (2, 2, 256, 4, 64), jnp.bfloat16)
+        k = jax.random.normal(ks[1], (2, 2, 256, 2, 64), jnp.bfloat16)
+        v = jax.random.normal(ks[2], (2, 2, 256, 2, 64), jnp.bfloat16)
+        do = jax.random.normal(ks[3], (2, 2, 256, 4, 64), jnp.bfloat16)
+        kw = dict(window=0, q_block=128, kv_block=128)
+
+        def kernel(q, k, v):
+            placement = A._kernel_placement(q, k, v, True)
+            assert placement[1][2] == "model", placement
+            return A._flash_kernel(q, k, v, placement=placement,
+                                   interpret=True, **kw)
+
+        def step_of(attn):
+            def per_pod(q, k, v, do):
+                out, vjp = jax.vjp(attn, q, k, v)
+                return (out, *vjp(do))
+
+            @jax.jit
+            def step(q, k, v, do):
+                with sctx.use(mesh, activation_spec(None, mesh)):
+                    return jax.vmap(per_pod, spmd_axis_name="pod")(q, k, v, do)
+            return step
+
+        step = step_of(kernel)
+        text = step.lower(q, k, v, do).compile().as_text()
+        assert "all-gather" not in text
+        got = step(q, k, v, do)
+        blocked = partial(A._flash_blocked, causal=True, **kw)
+        want = step_of(blocked)(q, k, v, do)
+        for a, b in zip(got, want):
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            scale = np.abs(b).max()
+            np.testing.assert_allclose(a, b, rtol=2e-2, atol=2e-2 * scale)
+        print("OK")
+    """, n_devices=4)
+    assert "OK" in out

@@ -136,3 +136,53 @@ def test_smoke_train_step_fits_one_v5e(smoke, topo):
     need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
     assert need < V5E_HBM, f"{need / 2**30:.2f} GiB of {V5E_HBM / 2**30}"
+
+
+def test_four_pod_step_runs_flash_kernel_per_device(smoke, topo, monkeypatch):
+    """The 4-pod phi3 step (chip_smoke.py's config, batch 1 per pod) for a
+    described v5e:2x2: its attention runs the Pallas kernel under the
+    ``attention.flash`` scope, inside the per-pod vmap (where the benchmark's
+    layer rule counts fwd/bwd), with no collective beyond those of the same
+    step on the blocked path, and the step fits a v5e."""
+    import collections
+    import re
+
+    from repro.launch.mesh import make_host_mesh
+    from repro.models import attention
+    from repro.runtime.train import build_train_step, make_batch_defs
+
+    _, cfg = smoke.smoke_config()
+    mesh = make_host_mesh((4, 1, 1), ("pod", "data", "model"),
+                          devices=topo.devices)
+
+    def compile_step():
+        build = build_train_step(cfg, smoke.elastic_config(), mesh, n_pods=4,
+                                 per_pod_batch=1, seq=smoke.SEQ)
+        return build.step.lower(
+            build.abstract_state,
+            make_batch_defs(cfg, 4, 1, smoke.SEQ)).compile()
+
+    def collectives(text):
+        return collections.Counter(re.findall(
+            r"\b(all-reduce|all-gather|reduce-scatter|all-to-all|"
+            r"collective-permute)(?:-start)?\(", text))
+
+    compiled = compile_step()
+    # an instruction's frontend attributes may span lines: join them
+    text = re.sub(r"\n(?=[\"}])", " ", compiled.as_text())
+    kernel_ops = [re.search(r'op_name="([^"]*)"', line).group(1)
+                  for line in text.splitlines()
+                  if "custom-call(" in line and "tpu_custom_call" in line]
+    assert kernel_ops
+    for op_name in kernel_ops:
+        assert "/attention.flash/" in op_name, op_name
+        assert op_name.startswith("jit(sync_easgd_step)/vmap("), op_name
+    ma = compiled.memory_analysis()
+    need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert need < V5E_HBM, f"{need / 2**30:.2f} GiB of {V5E_HBM / 2**30}"
+
+    monkeypatch.setattr(attention, "_kernel_placement", lambda *a: None)
+    blocked = compile_step().as_text()
+    assert "tpu_custom_call" not in blocked
+    assert not collectives(text) - collectives(blocked)
