@@ -71,6 +71,7 @@ def _causal_conv(x, w, b):
     return out + b[None, None]
 
 
+@jax.named_scope("ssm.scan")
 def _ssd_chunked(xh, dt, A, Bm, Cm, chunk: int, state0=None):
     """Chunked SSD scan.
 
@@ -113,9 +114,13 @@ def _ssd_chunked(xh, dt, A, Bm, Cm, chunk: int, state0=None):
         # intra-chunk (the quadratic/matmul part)
         g = jnp.einsum("bln,bmn->blm", cc.astype(jnp.float32),
                        bc.astype(jnp.float32))      # (B,L,L)
-        dec = jnp.exp(cum[:, :, None, :] - cum[:, None, :, :])  # (B,L,L,H)
-        mask = jnp.tril(jnp.ones((L, L), bool))
-        m = jnp.where(mask[None, :, :, None], g[..., None] * dec, 0.0)
+        # mask before exp: above the diagonal the exponent is positive and
+        # can pass float32's range; an inf masked after exp is 0 going
+        # forward but 0 * inf = NaN in the backward pass
+        mask = jnp.tril(jnp.ones((L, L), bool))[None, :, :, None]
+        dec = jnp.exp(jnp.where(mask, cum[:, :, None, :] - cum[:, None, :, :],
+                                -jnp.inf))                  # (B,L,L,H)
+        m = g[..., None] * dec
         y_intra = jnp.einsum("blmh,bmhp->blhp", m, xc.astype(jnp.float32))
 
         # state passing: S_new = exp(total)·S + Σ_j exp(total-cum_j) B_j x_jᵀ
@@ -145,6 +150,7 @@ def ssd_step(state, x_t, dt_t, A, B_t, C_t):
     return state, y
 
 
+@jax.named_scope("ssm")
 def ssm_block(cfg: ModelConfig, p, x, positions=None, *, cache=None,
               cache_pos=None, **_unused):
     """Mamba-2 block. cache = {conv: (B,K-1,convdim), state: (B,H,P,N)}."""

@@ -67,6 +67,13 @@ def _smoke_param_elems(smoke) -> int:
         abstract_params(tfm.model_defs(cfg), cfg.param_dtype)))
 
 
+def _assert_fits(compiled):
+    ma = compiled.memory_analysis()
+    need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert need < V5E_HBM, f"{need / 2**30:.2f} GiB of {V5E_HBM / 2**30}"
+
+
 def _compile(fn, *shapes):
     compiled = jax.jit(fn).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -132,10 +139,28 @@ def test_smoke_train_step_fits_one_v5e(smoke, topo):
     compiled = build.step.lower(
         build.abstract_state,
         make_batch_defs(cfg, 1, 1, smoke.SEQ)).compile()
-    ma = compiled.memory_analysis()
-    need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
-            - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
-    assert need < V5E_HBM, f"{need / 2**30:.2f} GiB of {V5E_HBM / 2**30}"
+    _assert_fits(compiled)
+
+
+def test_mamba2_cell_step_fits_one_v5e(topo):
+    """The step of the benchmark's ``mamba2_b8s4096_1chip`` cell (14 of
+    mamba2-780m's 48 layers at published widths, one worker, batch 8 x
+    4096, the chunked scan at chunk 256) compiles for a v5e and fits its
+    HBM."""
+    from bench import run, spec
+    from repro.launch.mesh import make_host_mesh
+    from repro.runtime.train import build_train_step, make_batch_defs
+    bench = spec.benchmark()
+    w = spec.workload(bench, "mamba2_b8s4096_1chip")
+    cfg = run.program_config(spec.config(bench, w["config"]))
+    t = spec.traffic(w["traffic"])
+    B, S = t["batch_per_worker"], t["seq"]
+    mesh = make_host_mesh((1, 1), ("data", "model"),
+                          devices=topo.devices[:1])
+    build = build_train_step(cfg, run.elastic_config(t), mesh, n_pods=1,
+                             per_pod_batch=B, seq=S)
+    _assert_fits(build.step.lower(build.abstract_state,
+                                  make_batch_defs(cfg, 1, B, S)).compile())
 
 
 def test_four_pod_step_runs_flash_kernel_per_device(smoke, topo, monkeypatch):
@@ -177,10 +202,7 @@ def test_four_pod_step_runs_flash_kernel_per_device(smoke, topo, monkeypatch):
     for op_name in kernel_ops:
         assert "/attention.flash/" in op_name, op_name
         assert op_name.startswith("jit(sync_easgd_step)/vmap("), op_name
-    ma = compiled.memory_analysis()
-    need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
-            - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
-    assert need < V5E_HBM, f"{need / 2**30:.2f} GiB of {V5E_HBM / 2**30}"
+    _assert_fits(compiled)
 
     monkeypatch.setattr(attention, "_kernel_placement", lambda *a: None)
     blocked = compile_step().as_text()
