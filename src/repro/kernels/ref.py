@@ -51,18 +51,22 @@ def fused_ce_ref(h, w, targets):
 
 
 def ssd_intra_ref(a, x, b, c, *, chunk: int):
-    """Intra-chunk SSD: per chunk, Y[i] = Σ_{j≤i} (C_i·B_j) e^{cum_i−cum_j} X_j."""
-    BH, S = a.shape
+    """Intra-chunk SSD, per chunk and head h:
+    Y[i] = Σ_{j≤i} (C_i·B_j) e^{cum_i−cum_j} X_j.
+    a: (R, S, H) log-decay; x: (R, S, H, P); b, c: (R, S, N), one group
+    shared by every head. The exponent is masked before exp (above the
+    diagonal it can pass float32's range; 0·inf is NaN in a gradient)."""
+    R, S, H, P = x.shape
     L = min(chunk, S)
     nc = S // L
-    a_ = a.reshape(BH, nc, L).astype(jnp.float32)
-    x_ = x.reshape(BH, nc, L, -1).astype(jnp.float32)
-    b_ = b.reshape(BH, nc, L, -1).astype(jnp.float32)
-    c_ = c.reshape(BH, nc, L, -1).astype(jnp.float32)
-    cum = jnp.cumsum(a_, axis=2)
-    g = jnp.einsum("hcln,hcmn->hclm", c_, b_)
-    dec = jnp.exp(cum[..., :, None] - cum[..., None, :])
-    mask = jnp.tril(jnp.ones((L, L), bool))
-    m = jnp.where(mask[None, None], g * dec, 0.0)
-    y = jnp.einsum("hclm,hcmp->hclp", m, x_)
-    return y.reshape(BH, S, -1).astype(x.dtype)
+    a_ = a.reshape(R, nc, L, H).astype(jnp.float32)
+    x_ = x.reshape(R, nc, L, H, P).astype(jnp.float32)
+    b_ = b.reshape(R, nc, L, -1).astype(jnp.float32)
+    c_ = c.reshape(R, nc, L, -1).astype(jnp.float32)
+    cum = jnp.cumsum(a_, axis=2)                          # (R, nc, L, H)
+    g = jnp.einsum("rcln,rcmn->rclm", c_, b_)
+    mask = jnp.tril(jnp.ones((L, L), bool))[None, None, :, :, None]
+    dec = jnp.exp(jnp.where(mask, cum[:, :, :, None] - cum[:, :, None],
+                            -jnp.inf))                    # (R, nc, L, L, H)
+    y = jnp.einsum("rclm,rclmh,rcmhp->rclhp", g, dec, x_)
+    return y.reshape(R, S, H, P).astype(x.dtype)

@@ -9,19 +9,25 @@ Training/prefill uses the CHUNKED form (the paper's matmul-friendly
 decomposition, which is exactly what the MXU wants):
   * intra-chunk: quadratic attention-like matmuls within a chunk,
   * inter-chunk: a sequential scan over chunk states.
-We scan over chunks (lax.scan) so the (L×L) decay tensor exists for one
-chunk at a time — heads shard over `model`, batch over `data`, keeping the
-per-device tile VMEM-sized. This mirrors the Pallas kernel's blocking
-(kernels/ssd_chunk.py); this function is also its oracle.
+``_ssd_chunked`` scans over chunks (lax.scan) so the (L×L) decay tensor
+exists for one chunk at a time; it is the oracle of the TPU path. Lowered for
+a TPU, ``ssd_scan`` computes every chunk's contractions over its positions
+(the intra-chunk term, the state it adds, the term from the state entering
+it) at once in Pallas kernels (kernels/ssd_chunk.py) whose (L×L) tiles stay
+in VMEM, and the lax.scan keeps only the elementwise state recurrence.
 
 Decode is the O(1) recurrent step on the carried (B, H, P, N) state.
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec
 
+from repro.kernels import ssd_chunk
 from repro.models import sctx
 from repro.models.common import ModelConfig, ParamDef
 
@@ -139,6 +145,117 @@ def _ssd_chunked(xh, dt, A, Bm, Cm, chunk: int, state0=None):
     return y, state
 
 
+def _kernel_placement(xh, Bm, chunk):
+    """Where the chunk kernels run these shapes: (mesh, each operand kind's
+    spec, as ``ssd_chunk`` names the kinds) on the installed mesh, (None,
+    None) with none installed, or None where the kernels refuse them. They
+    take one group of B and C, a chunk that is a multiple of 128 lanes,
+    whole sequences per device, and per device a number of heads that
+    ``ssd_chunk.head_block`` can tile."""
+    Bsz, S, H, P = xh.shape
+    if Bm.ndim != 3 or min(chunk, S) % ssd_chunk.LANES:
+        return None
+    lay = sctx.layout()
+    if lay is None:
+        return (None, None) if ssd_chunk.head_block(H, P) else None
+    batch, seq, heads, _ = lay.spec(xh.shape,
+                                    ("batch", "seq", "heads", "head_dim"))
+    n_heads = lay.mesh.shape[heads] if heads else 1
+    if seq is not None or not ssd_chunk.head_block(H // n_heads, P):
+        return None
+    return lay.mesh, {"x": PartitionSpec(batch, None, heads),
+                      "rows": PartitionSpec(batch, heads, None),
+                      "bc": PartitionSpec(batch, None, None),
+                      "s": PartitionSpec(None, batch, heads, None)}
+
+
+def _per_device(placement, kernel, ins, out, *args):
+    """``kernel(*args)`` under the scope that records the chunk kernels, per
+    device under ``jax.shard_map`` over the installed mesh (batch over data,
+    heads over model; its transpose sums dB and dC over the head shards);
+    ``ins`` and ``out`` name the operands' specs."""
+    mesh, specs = placement
+    with jax.named_scope("ssm.scan.kernel"):
+        if mesh is None:
+            return kernel(*args)
+        return jax.shard_map(kernel, mesh=mesh,
+                             in_specs=tuple(specs[k] for k in ins),
+                             out_specs=specs[out], check_vma=False)(*args)
+
+
+@jax.named_scope("ssm.scan")
+def _ssd_chunked_kernel(xh, dt, A, Bm, Cm, chunk: int, state0=None, *,
+                        placement, interpret=False):
+    """``_ssd_chunked`` with its contractions over each chunk's positions in
+    the Pallas kernels of ``kernels/ssd_chunk``: the state each chunk adds
+    (``chunk_states``) and its output (``chunk_output``), every chunk in one
+    call each. Between them a lax.scan passes the state from chunk to chunk,
+    S ← exp(cum_{L−1})·S + S_in, the same recurrence as ``_ssd_chunked``'s.
+    B and C go in as float32, as the jnp path multiplies them, so that
+    their gradients are summed in float32 and rounded once."""
+    Bsz, S, H, P = xh.shape
+    N = Bm.shape[-1]
+    L = min(chunk, S)
+    pad = (-S) % L
+    if pad:
+        # dt=0 padding: decay=1 and zero input, so the state is unaffected
+        xh = jnp.pad(xh, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
+        Bm = jnp.pad(Bm, ((0, 0), (0, pad), (0, 0)))
+        Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0)))
+        S = S + pad
+    nc = S // L
+
+    a = (dt * A[None, None, :]).astype(jnp.float32)  # (B,S,H) log-decay ≤ 0
+    cum = jnp.cumsum(a.reshape(Bsz, nc, L, H), axis=2)   # from chunk start
+    rows = lambda t: t.reshape(Bsz, S, H).transpose(0, 2, 1)   # (B,H,S)
+    # x and dt go in apart: the kernels form Δt·x in VMEM
+    args = (rows(cum), rows(dt.astype(jnp.float32)),
+            xh.reshape(Bsz, S, H * P), Bm.astype(jnp.float32))
+    s_in = _per_device(placement, partial(ssd_chunk.chunk_states, chunk=L,
+                                          interpret=interpret),
+                       ("rows", "rows", "x", "bc"), "s", *args)
+
+    # state passing: S_new = exp(total)·S + S_in, states (B, H·P, N)
+    decay = jnp.repeat(jnp.exp(cum[:, :, -1]), P, axis=-1)  # (B,nc,H·P)
+    if state0 is None:
+        state0 = jnp.zeros((Bsz, H, P, N), jnp.float32)
+
+    def chunk_step(state, inp):
+        d, s = inp
+        return state * d[..., None] + s, state
+
+    state, s_prev = lax.scan(chunk_step, state0.reshape(Bsz, H * P, N),
+                             (decay.swapaxes(0, 1), s_in))
+    y = _per_device(placement, partial(ssd_chunk.chunk_output, chunk=L,
+                                       interpret=interpret),
+                    ("rows", "rows", "x", "bc", "bc", "s"), "x", *args,
+                    Cm.astype(jnp.float32), s_prev)
+    y = y.reshape(Bsz, S, H, P)
+    if pad:
+        y = y[:, :S - pad]
+    return y, state.reshape(Bsz, H, P, N)
+
+
+def ssd_scan(xh, dt, A, Bm, Cm, chunk: int, state0=None):
+    """The chunked SSD scan. Lowered for a TPU, the shapes the chunk
+    kernels take (``_kernel_placement``) run ``_ssd_chunked_kernel``; every
+    other shape, and every other platform, runs ``_ssd_chunked``."""
+    placement = _kernel_placement(xh, Bm, chunk)
+    if placement is None:
+        return _ssd_chunked(xh, dt, A, Bm, Cm, chunk, state0)
+
+    def kernel(xh, dt, A, Bm, Cm, state0):
+        return _ssd_chunked_kernel(xh, dt, A, Bm, Cm, chunk, state0,
+                                   placement=placement)
+
+    def jnp_path(xh, dt, A, Bm, Cm, state0):
+        return _ssd_chunked(xh, dt, A, Bm, Cm, chunk, state0)
+
+    return lax.platform_dependent(xh, dt, A, Bm, Cm, state0, tpu=kernel,
+                                  default=jnp_path)
+
+
 def ssd_step(state, x_t, dt_t, A, B_t, C_t):
     """O(1) decode recurrence. state: (B,H,P,N); x_t: (B,H,P);
     dt_t: (B,H); B_t, C_t: (B,N)."""
@@ -193,10 +310,12 @@ def ssm_block(cfg: ModelConfig, p, x, positions=None, *, cache=None,
         A = -jnp.exp(p["A_log"].astype(jnp.float32))
         xh = sctx.shard(xi.reshape(B_, S, n_heads, s.head_dim),
                         "batch", "seq", "heads", "head_dim")
-        y, state = _ssd_chunked(xh.astype(jnp.float32), dt_sp, A, Bm, Cm,
-                                s.chunk)
-        y = y + p["D"].astype(jnp.float32)[None, None, :, None] * xh
-        y = y.reshape(B_, S, d_inner)
+        y, state = ssd_scan(xh.astype(jnp.float32), dt_sp, A, Bm, Cm,
+                            s.chunk)
+        # the skip in (B, S, d_inner): with head_dim minor, XLA lays x-sized
+        # arrays out sequence-minor and copies them to and from the kernels
+        y = y.reshape(B_, S, d_inner) + jnp.repeat(
+            p["D"].astype(jnp.float32), s.head_dim) * xi
         new_cache = cache
         if cache is not None:
             K = s.d_conv

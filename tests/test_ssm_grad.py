@@ -10,6 +10,7 @@ and a sequence of two chunks, with step sizes large enough that the
 exponent passes 88.7.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -104,3 +105,27 @@ def test_grad_finite_and_matches_reference_at_chunk_256(model):
         assert np.all(np.isfinite(a)), name
         gaps[name] = float(np.linalg.norm(a - b) / np.linalg.norm(b))
     assert max(gaps.values()) < TOL, gaps
+
+
+def test_kernel_path_grad_finite_and_matches_jnp_path_at_chunk_256(
+        model, monkeypatch):
+    """The scan as a TPU lowering runs it, in the Pallas chunk kernels and
+    their backward kernels (interpreted here), on the same model, batch and
+    step sizes: every gradient finite, and each leaf within TOL of the jnp
+    path's."""
+    cfg, m, mod, params, batch = model
+
+    def program(prm):
+        return tfm.lm_loss(cfg, prm, batch)[0]
+
+    with jax.default_matmul_precision("highest"):
+        lj, gj = jax.jit(jax.value_and_grad(program))(params)
+        monkeypatch.setattr(ssm, "ssd_scan", functools.partial(
+            ssm._ssd_chunked_kernel, placement=(None, None), interpret=True))
+        lk, gk = jax.jit(jax.value_and_grad(program))(params)
+    assert abs(float(lk) - float(lj)) < TOL * abs(float(lj))
+    for name, a, b in zip(_by_name(mod, m, gk), jax.tree_util.tree_leaves(gk),
+                          jax.tree_util.tree_leaves(gj)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.all(np.isfinite(a)), name
+        assert np.linalg.norm(a - b) < TOL * np.linalg.norm(b), name

@@ -10,6 +10,7 @@ should. Keep every such compile in this one file.
 import functools
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -100,21 +101,35 @@ def test_fused_cross_entropy_compiles_at_phi3_vocab(one_chip):
              sd((8192,), jnp.int32))
 
 
-def test_ssd_intra_chunk_compiles_at_mamba2_widths(one_chip):
-    # mamba2: 48 heads of P=64, state N=128, chunk 256; batch 2 x 4096
-    BH, S, P, N = 2 * 48, 4096, 64, 128
+@pytest.mark.parametrize("kernel", ["chunk_states", "chunk_output"])
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_ssd_intra_chunk_compiles_at_mamba2_widths(one_chip, kernel,
+                                                   direction):
+    """The SSD chunk kernels, and their backward kernels, at the mamba2
+    cell's widths: 48 heads of P=64 sharing one B and C of N=128, chunk
+    256, 8 rows of 4096; B and C per row, never broadcast per head."""
+    from repro.kernels import ssd_chunk
+    R, S, H, P, N, L = 8, 4096, 48, 64, 128, 256
     sd = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    _compile(functools.partial(ops.ssd_intra_chunk, chunk=256,
-                               interpret=False),
-             sd((BH, S), jnp.float32), sd((BH, S, P), jnp.bfloat16),
-             sd((BH, S, N), jnp.bfloat16), sd((BH, S, N), jnp.bfloat16))
+    rows, x = sd((R, H, S), jnp.float32), sd((R, S, H * P), jnp.float32)
+    bc, s = sd((R, S, N), jnp.float32), sd((S // L, R, H * P, N), jnp.float32)
+    if kernel == "chunk_states":
+        fn, args, out = ssd_chunk.chunk_states, (rows, rows, x, bc), s
+    else:
+        fn, args, out = ssd_chunk.chunk_output, (rows, rows, x, bc, bc, s), x
+    fn = functools.partial(fn, chunk=L)
+    if direction == "forward":
+        _compile(fn, *args)
+    else:
+        _compile(lambda d, *a: jax.vjp(fn, *a)[1](d), out, *args)
 
 
 def test_ssd_intra_chunk_refuses_unaligned_chunk():
-    a = jnp.zeros((1, 64))
-    x = jnp.zeros((1, 64, 8))
+    a = jnp.zeros((1, 64, 2))
+    x = jnp.zeros((1, 64, 2, 64))
+    b = jnp.zeros((1, 64, 8))
     with pytest.raises(ValueError, match="multiples of 128"):
-        ops.ssd_intra_chunk(a, x, x, x, chunk=64, interpret=False)
+        ops.ssd_intra_chunk(a, x, b, b, chunk=64, interpret=False)
 
 
 def test_flash_attention_compiles_at_phi3_widths(one_chip):
@@ -159,8 +174,29 @@ def test_mamba2_cell_step_fits_one_v5e(topo):
                           devices=topo.devices[:1])
     build = build_train_step(cfg, run.elastic_config(t), mesh, n_pods=1,
                              per_pod_batch=B, seq=S)
-    _assert_fits(build.step.lower(build.abstract_state,
-                                  make_batch_defs(cfg, 1, B, S)).compile())
+    compiled = build.step.lower(build.abstract_state,
+                                make_batch_defs(cfg, 1, B, S)).compile()
+    _assert_fits(compiled)
+    # its scan runs the two chunk kernels, forward (twice, with the remat)
+    # and backward, under the scope that records them on the device trace
+    kernel_ops = _kernel_op_names(compiled.as_text())
+    assert len(kernel_ops) == 6, kernel_ops
+    for op_name in kernel_ops:
+        assert "/ssm.scan/ssm.scan.kernel/" in op_name, op_name
+    # no more memory than the jnp scan's step took (14.328 GiB)
+    ma = compiled.memory_analysis()
+    need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert need <= 14.328 * 2**30, need / 2**30
+
+
+def _kernel_op_names(text: str) -> list:
+    """op_name of every Mosaic kernel call in a compiled module's text (an
+    instruction's frontend attributes may span lines: joined first)."""
+    text = re.sub(r"\n(?=[\"}])", " ", text)
+    return [re.search(r'op_name="([^"]*)"', line).group(1)
+            for line in text.splitlines()
+            if "custom-call(" in line and "tpu_custom_call" in line]
 
 
 def test_four_pod_step_runs_flash_kernel_per_device(smoke, topo, monkeypatch):
@@ -170,7 +206,6 @@ def test_four_pod_step_runs_flash_kernel_per_device(smoke, topo, monkeypatch):
     layer rule counts fwd/bwd), with no collective beyond those of the same
     step on the blocked path, and the step fits a v5e."""
     import collections
-    import re
 
     from repro.launch.mesh import make_host_mesh
     from repro.models import attention
@@ -193,11 +228,8 @@ def test_four_pod_step_runs_flash_kernel_per_device(smoke, topo, monkeypatch):
             r"collective-permute)(?:-start)?\(", text))
 
     compiled = compile_step()
-    # an instruction's frontend attributes may span lines: join them
-    text = re.sub(r"\n(?=[\"}])", " ", compiled.as_text())
-    kernel_ops = [re.search(r'op_name="([^"]*)"', line).group(1)
-                  for line in text.splitlines()
-                  if "custom-call(" in line and "tpu_custom_call" in line]
+    text = compiled.as_text()
+    kernel_ops = _kernel_op_names(text)
     assert kernel_ops
     for op_name in kernel_ops:
         assert "/attention.flash/" in op_name, op_name
