@@ -1,5 +1,5 @@
-"""Benchmark driver: one module per paper table/figure (+ kernels +
-roofline). Prints ``name,us_per_call,derived`` CSV rows.
+"""Benchmark runner: one module per paper table/figure (+ the
+roofline table). Prints ``name,us_per_call,derived`` CSV rows.
 
     PYTHONPATH=src python -m benchmarks.run [--quick] [--only fig6_8,...]
                                             [--json]
@@ -27,7 +27,6 @@ MODULES = (
     "fig10_packing",        # Fig 10: packed vs per-layer communication
     "fig12_partitioning",   # Fig 12: chip partitioning sweep
     "table4_weakscaling",   # Table 4: weak scaling to 4352 cores
-    "kernels_bench",        # Pallas kernel oracles + TPU projections
     "roofline",             # §Roofline table from the dry-run JSONL
 )
 

@@ -7,8 +7,7 @@ depend on that for sub-second startup.
 """
 _EASGD = ("EASGDConfig", "sgd_update", "msgd_update", "easgd_worker_update",
           "measgd_worker_update", "center_update_from_sum",
-          "center_update_from_mean", "center_update_single",
-          "fused_elastic_step_flat")
+          "center_update_from_mean", "center_update_single")
 _ELASTIC = {"ElasticConfig": "ElasticConfig", "ElasticState": "ElasticState",
             "elastic_init": "init",
             "elastic_apply_gradients": "apply_gradients",

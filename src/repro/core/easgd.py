@@ -127,26 +127,3 @@ def center_update_single(center, w_i, cfg: EASGDConfig):
     return tree_map(
         lambda c_, w_: c_ + a * (w_.astype(c_.dtype) - c_), center, w_i
     )
-
-
-# ---------------------------------------------------------------------------
-# fused packed-buffer form (what the Pallas kernel implements)
-# ---------------------------------------------------------------------------
-
-def fused_elastic_step_flat(w_flat, v_flat, g_flat, c_flat, mean_w_flat,
-                            n_workers: int, cfg: EASGDConfig):
-    """One fused pass over the packed buffers: eqs 5–6 + eq 2.
-
-    This is the pure-jnp oracle for ``kernels/elastic_update.py`` and the
-    reference semantics of the packed Sync-EASGD step:
-
-        V  ← μV − ηG
-        W  ← W + V − ηρ(W − C)
-        C  ← C + ηρP(mean_W − C)      # mean over workers of PRE-update W
-
-    All buffers are 1-D and the same dtype (the packer guarantees this).
-    """
-    v_new = cfg.mu * v_flat - cfg.eta * g_flat
-    w_new = w_flat + v_new - cfg.eta * cfg.rho * (w_flat - c_flat)
-    c_new = c_flat + cfg.alpha * n_workers * (mean_w_flat - c_flat)
-    return w_new, v_new, c_new

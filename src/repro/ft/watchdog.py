@@ -36,11 +36,16 @@ class Watchdog:
         if self.heartbeat_path is None or self._hb_thread is not None:
             return self
 
+        # each beat is written beside the file and swapped in whole, so a
+        # reader never sees the empty file an in-place rewrite leaves
+        tmp = self.heartbeat_path + ".tmp"
+
         def beat():
             while not self.should_stop.is_set():
                 try:
-                    with open(self.heartbeat_path, "w") as f:
+                    with open(tmp, "w") as f:
                         f.write(str(time.time()))
+                    os.replace(tmp, self.heartbeat_path)
                 except OSError:
                     pass
                 self.should_stop.wait(self.interval_s)

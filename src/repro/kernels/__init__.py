@@ -1,4 +1,2 @@
-from repro.kernels import ops, ref
-from repro.kernels.ops import (
-    flash_attention, elastic_update, ssd_intra_chunk, fused_cross_entropy,
-)
+"""Pallas TPU kernels. The package imports nothing, so loading a kernel
+module loads neither ``repro.models`` nor ``repro.core``."""

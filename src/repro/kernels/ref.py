@@ -1,19 +1,8 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose targets)."""
+"""Pure-jnp oracles, independent of the program (the allclose targets)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-from repro.core.easgd import EASGDConfig, fused_elastic_step_flat
-from repro.models.attention import blocked_attention
-
-
-def flash_attention_ref(q, k, v, *, causal=True, window=0):
-    """q,k,v: (BH, S, D) — same-heads attention via the blocked oracle."""
-    out = blocked_attention(q[:, :, None].swapaxes(1, 2).swapaxes(1, 1),
-                            k[:, :, None], v[:, :, None],
-                            causal=causal, window=window)
-    return out[:, :, 0]
 
 
 def flash_attention_dense_ref(q, k, v, *, causal=True, window=0):
@@ -30,24 +19,6 @@ def flash_attention_dense_ref(q, k, v, *, causal=True, window=0):
     s = jnp.where(m[None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bqk,bkd->bqd", p, v.astype(jnp.float32)).astype(q.dtype)
-
-
-def elastic_update_ref(w, v, g, c, mean_w, *, eta, rho, mu, n_workers):
-    cfg = EASGDConfig(eta=eta, rho=rho, mu=mu)
-    w32, v32, g32, c32, m32 = (x.astype(jnp.float32)
-                               for x in (w, v, g, c, mean_w))
-    w2, v2, c2 = fused_elastic_step_flat(w32, v32, g32, c32, m32,
-                                         n_workers, cfg)
-    return w2.astype(w.dtype), v2.astype(v.dtype), c2.astype(c.dtype)
-
-
-def fused_ce_ref(h, w, targets):
-    """Dense reference: loss_t = logsumexp(h·W) − (h·W)[target]."""
-    logits = jnp.einsum("td,dv->tv", h.astype(jnp.float32),
-                        w.astype(jnp.float32))
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    tgt = jnp.take_along_axis(logits, targets[:, None], axis=1)[:, 0]
-    return lse - tgt
 
 
 def ssd_intra_ref(a, x, b, c, *, chunk: int):

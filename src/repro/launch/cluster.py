@@ -128,10 +128,6 @@ def main(argv=None):
                     help="p2p: run the bucketed exchange inline instead of "
                          "pipelined (the no-overlap baseline; math is "
                          "bitwise identical either way)")
-    ap.add_argument("--update-backend", default="numpy",
-                    choices=["numpy", "pallas"],
-                    help="p2p per-bucket update: easgd_flat numpy or the "
-                         "fused Pallas elastic-update kernel")
     ap.add_argument("--trace", action="store_true",
                     help="record per-thread spans on every worker and the "
                          "master, merge onto the master clock (obs.clock "
@@ -178,10 +174,6 @@ def main(argv=None):
         if bad:
             ap.error(f"--sync-plane p2p applies to the sync family only; "
                      f"{bad} exchange through the master by definition")
-    if args.update_backend == "pallas" and (args.transport != "tcp"
-                                            or args.sync_plane != "p2p"):
-        ap.error("--update-backend pallas rides the p2p worker loop "
-                 "(--transport tcp --sync-plane p2p)")
     if args.elastic and args.transport != "tcp":
         ap.error("--elastic reconfigures real links (tcp only)")
     easgd = EASGDConfig(eta=args.eta, rho=args.rho, mu=0.9, tau=args.tau)
@@ -229,7 +221,6 @@ def main(argv=None):
         sync_plane=args.sync_plane,
         topology=topology,
         bucket_bytes=args.bucket_bytes, overlap=not args.no_overlap,
-        update_backend=args.update_backend,
         trace=args.trace or bool(args.trace_dir),
         trace_dir=args.trace_dir,
         telemetry=args.telemetry,

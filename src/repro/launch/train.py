@@ -220,12 +220,9 @@ def run_ps_mode(args) -> list:
     from repro.core.easgd_flat import SYNC_FAMILY as _SYNC
     for algo in algos:
         # the p2p plane only exists for the sync family; `--algorithm all
-        # --sync-plane p2p` runs the rest through the master as usual —
-        # and the fused-kernel update path rides the p2p worker loop only
+        # --sync-plane p2p` runs the rest through the master as usual
         plane = args.sync_plane if algo in _SYNC else "master"
-        backend = args.update_backend if plane == "p2p" else "numpy"
-        cfg = _dc.replace(base, algorithm=algo, sync_plane=plane,
-                          update_backend=backend)
+        cfg = _dc.replace(base, algorithm=algo, sync_plane=plane)
         res, _, rec = ps.run_vs_des(problem, easgd, cfg, cal=cal)
         print(f"{algo:16s} [{res.transport}/{res.schedule}] "
               f"iters={res.total_iters} err={res.final_metric:.3f} "
@@ -302,11 +299,6 @@ def main(argv=None):
                          "(0 = monolithic). With the tcp p2p plane buckets "
                          "stream while compute runs — bitwise-identical "
                          "math, overlapped wire")
-    ap.add_argument("--update-backend", default="numpy",
-                    choices=["numpy", "pallas"],
-                    help="p2p per-bucket update path: easgd_flat numpy or "
-                         "the fused Pallas elastic-update kernel (bitwise "
-                         "under the worker's pinned XLA flags)")
     ap.add_argument("--sync-plane", default="master",
                     choices=["master", "p2p"],
                     help="tcp sync family: 'p2p' executes Schedule.rounds "

@@ -81,22 +81,17 @@ def wire_payload_nbytes(n_elements: int, codec: str) -> int:
     return n_elements * 8
 
 
-def worker_env(pallas: bool = False) -> dict:
+def worker_env() -> dict:
     """Environment for a spawned worker interpreter: the repo's src dir on
     PYTHONPATH (shared by the training spawn and the calibration burners —
     one definition of how a worker process is launched). A worker never
     holds an accelerator: a chip belongs to one process, and the parent
-    may be that process, so every child is pinned to the CPU backend.
-    ``pallas`` also pins the XLA CPU backend to a no-FMA ISA BEFORE the
-    child's first jax import, so the fused elastic-update kernel stays
-    bitwise equal to easgd_flat (see kernels/elastic_update.py)."""
+    may be that process, so every child is pinned to the CPU backend."""
     src = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     env = dict(os.environ)
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     env["JAX_PLATFORMS"] = "cpu"
-    if pallas:
-        env.setdefault("XLA_FLAGS", "--xla_cpu_max_isa=SSE4_2")
     return env
 
 
@@ -120,13 +115,12 @@ def cluster_spec_env(role: str, wid: int, host: str, port: int,
 
 def spawn_local_workers(host: str, port: int, n_workers: int,
                         token: str = DEFAULT_TOKEN,
-                        pallas: bool = False,
                         env_extra: dict | None = None) -> list:
     """Launch localhost worker processes (fresh interpreters — the same
     isolation a remote host gives, minus the cable). Each child also gets a
     ``REPRO_CLUSTER_SPEC`` describing its own role, so a respawn is a
     re-exec; ``env_extra`` carries run-scoped injections (REPRO_CHAOS)."""
-    base = worker_env(pallas=pallas)
+    base = worker_env()
     if env_extra:
         base.update(env_extra)
     procs = []
@@ -438,8 +432,6 @@ class MasterServer:
                 "peers": {str(w): a for w, a in self.peer_addrs.items()},
                 "bucket_bounds": self.boundaries,
                 "overlap": getattr(cfg, "overlap", True),
-                "update_backend": getattr(cfg, "update_backend",
-                                          "numpy"),
                 "t_wire_bucket_s": ([slow * t for t in
                                      self._t_sync_wire_buckets(
                                          wid if self.topology is not None
@@ -1601,8 +1593,6 @@ def run_ps_tcp(problem, easgd, cfg, eval_fn_override=None,
     if spec is not None:
         env_extra = {ft_chaos.ENV_VAR: spec.to_env()}
     procs = (spawn_local_workers(
-        cfg.tcp_host, port, cfg.n_workers,
-        pallas=getattr(cfg, "update_backend", "numpy") == "pallas",
-        env_extra=env_extra)
+        cfg.tcp_host, port, cfg.n_workers, env_extra=env_extra)
         if cfg.spawn_workers else [])
     return master.run(listener, procs=procs)

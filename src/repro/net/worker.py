@@ -36,7 +36,6 @@ gradient is computed.
 from __future__ import annotations
 
 import argparse
-import functools
 import importlib
 import os
 import socket
@@ -317,10 +316,7 @@ def _p2p_sync_loop(link: Link, mesh: PeerMesh, cfg: dict, grad_fn,
     wire. Bucket updates are elementwise on disjoint slices in schedule
     order, so the iterates stay bitwise-identical to the monolithic path
     — overlap moves time, never math. ``overlap=False`` runs the same
-    bucketed exchange inline first (the paper's no-overlap baseline);
-    ``update_backend="pallas"`` applies each bucket through the fused
-    elastic-update kernel instead of easgd_flat (still bitwise — see
-    kernels/elastic_update.py for the ISA pin that makes it so).
+    bucketed exchange inline first (the paper's no-overlap baseline).
 
     Under ``elastic`` (WELCOME flag, from ``PSConfig.elastic``) this loop
     is also the worker half of the membership tentpole (ft.membership): a
@@ -345,7 +341,6 @@ def _p2p_sync_loop(link: Link, mesh: PeerMesh, cfg: dict, grad_fn,
     t_wire = float(cfg.get("t_wire_s", 0.0))
     bounds = cfg.get("bucket_bounds") or None
     overlap = bool(cfg.get("overlap", True))
-    backend = cfg.get("update_backend", "numpy")
     t_bucket = [float(x) for x in (cfg.get("t_wire_bucket_s") or [])]
     rounds = rounds_from_wire(cfg["rounds"])
     directory = {int(k): v for k, v in cfg["peers"].items()}
@@ -366,25 +361,6 @@ def _p2p_sync_loop(link: Link, mesh: PeerMesh, cfg: dict, grad_fn,
         # moment the next membership event lands)
         mesh.connect(directory, peer_pairs(rounds))
         mesh.set_rounds(rounds, padded, boundaries=bounds)
-
-    fused_easgd = fused_sgd = None
-    if backend == "pallas":
-        # first jax import in this (otherwise jax-free) process: pin the
-        # CPU backend to a no-FMA ISA so the fused kernel stays BITWISE
-        # equal to easgd_flat (XLA contracts a*b+c to fma otherwise);
-        # worker_env ships the same flags, setdefault keeps them. A worker
-        # never takes an accelerator: a chip belongs to one process.
-        if "jax" not in sys.modules:
-            os.environ["JAX_PLATFORMS"] = "cpu"
-            os.environ.setdefault("XLA_FLAGS", "--xla_cpu_max_isa=SSE4_2")
-        # importlib: the kernels package re-exports an `elastic_update`
-        # FUNCTION that shadows the submodule on attribute-style imports
-        # (f64, CPU-pinned: these kernels run interpreted by design)
-        _fk = importlib.import_module("repro.kernels.elastic_update")
-        fused_easgd = functools.partial(_fk.fused_sync_easgd_update,
-                                        interpret=True)
-        fused_sgd = functools.partial(_fk.fused_sync_sgd_update,
-                                      interpret=True)
 
     # -- elastic control plane: ONE thread owns the master link's inbound
     # side for the whole run (RECONFIGURE can land at any moment, so the
@@ -480,27 +456,17 @@ def _p2p_sync_loop(link: Link, mesh: PeerMesh, cfg: dict, grad_fn,
         a, b = u_spans[bidx]
         if a >= b:
             return
-        if fused_easgd is not None:
-            w[a:b], center[a:b] = fused_easgd(
-                w[a:b], grad[a:b], center[a:b], row[a:b], P,
-                local_cfg.eta, local_cfg.rho)
-        else:
-            easgd_flat.worker_step(algo, w[a:b], vel[a:b], grad[a:b],
-                                   center[a:b], local_cfg)
-            easgd_flat.sync_master_easgd(center[a:b], row[a:b] / P, P,
-                                         local_cfg)
+        easgd_flat.worker_step(algo, w[a:b], vel[a:b], grad[a:b],
+                               center[a:b], local_cfg)
+        easgd_flat.sync_master_easgd(center[a:b], row[a:b] / P, P,
+                                     local_cfg)
 
     def _apply_sgd(bidx):
         a, b = u_spans[bidx]
         if a >= b:
             return
-        if fused_sgd is not None:
-            center[a:b], vel[a:b] = fused_sgd(
-                center[a:b], vel[a:b], row[a:b], P,
-                local_cfg.eta, local_cfg.mu)
-        else:
-            easgd_flat.sync_master_sgd(center[a:b], vel[a:b],
-                                       row[a:b] / P, local_cfg)
+        easgd_flat.sync_master_sgd(center[a:b], vel[a:b],
+                                   row[a:b] / P, local_cfg)
 
     def _drain(apply_fn):
         """Apply each bucket's update as it lands; time blocked on the
@@ -762,7 +728,7 @@ def _p2p_sync_loop(link: Link, mesh: PeerMesh, cfg: dict, grad_fn,
         stats = mesh.stats()
         stats.update({"comm_s": comm_s, "exposed_s": exposed_s,
                       "overlapped_s": max(0.0, comm_s - exposed_s),
-                      "overlap": overlap, "update_backend": backend})
+                      "overlap": overlap})
         if mesh.host_of is not None:
             stats["host"] = mesh.host_of(wid)
         if elastic:

@@ -109,11 +109,6 @@ class PSConfig:
     #                                  (§6.1.3). False = the paper's
     #                                  no-overlap baseline — same math, the
     #                                  worker just waits out the wire
-    update_backend: str = "numpy"    # p2p worker update: "numpy"
-    #                                  (easgd_flat) or "pallas" (the fused
-    #                                  elastic-update kernel on the real
-    #                                  per-bucket path; workers are spawned
-    #                                  with XLA flags that keep it bitwise)
     # -- observability (repro.obs) ------------------------------------------
     trace: bool = False              # record per-thread spans (compute /
     #                                  waits / exchange rounds / buckets /
@@ -187,14 +182,6 @@ class PSConfig:
     def __post_init__(self):
         assert self.algorithm in ALGORITHMS, self.algorithm
         assert self.bucket_bytes >= 0, self.bucket_bytes
-        assert self.update_backend in ("numpy", "pallas"), \
-            self.update_backend
-        # the fused-kernel update path lives in the p2p worker loop — the
-        # shared-memory planes update through easgd_flat directly
-        assert self.update_backend == "numpy" or (
-            self.transport == "tcp" and self.sync_plane == "p2p"), (
-            f"update_backend='pallas' runs in the p2p worker loop "
-            f"(transport='{self.transport}', sync_plane='{self.sync_plane}')")
         assert self.wire_compression in ("none", "sign_ef"), \
             self.wire_compression
         # the shared-memory transports have no wire to compress — a config

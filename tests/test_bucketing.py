@@ -9,27 +9,17 @@
  3. Accounting: per-bucket mesh byte counters partition the registry's
     ``bytes_from_rounds`` total exactly; schedule-level counters are
     identical with and without bucketing.
- 4. The fused Pallas per-bucket update matches easgd_flat at ZERO
-    tolerance (subprocess with the pinned no-FMA XLA flags — the same
-    environment spawned p2p workers get).
 """
-import dataclasses
-import os
-import socket
-import subprocess
-import sys
 import threading
 
 import numpy as np
 import pytest
 
-from repro import comm, ps
+from repro import ps
 from repro.comm import rounds as comm_rounds
 from repro.core.easgd import EASGDConfig
 
 CFG = EASGDConfig(eta=0.05, rho=0.07, mu=0.9)
-SRC = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "src")
 
 
 # ---------------------------------------------------------------------------
@@ -140,22 +130,6 @@ def test_bucketed_bitwise_through_tcp_p2p_wire(overlap):
         assert p2p.counters["overlapped_s"] == 0.0
 
 
-def test_pallas_update_backend_bitwise_through_tcp_p2p():
-    """update_backend='pallas' puts the fused elastic-update kernel on
-    the real per-bucket path of spawned TCP workers (which get the no-FMA
-    XLA pin from worker_env) — and the run still lands on the monolithic
-    numpy thread run's exact bits."""
-    P, iters = 2, 8
-    mono = _thread_run("sync_easgd", P, "ring", bucket_bytes=0, iters=iters)
-    cfg = ps.PSConfig(algorithm="sync_easgd", n_workers=P,
-                      total_iters=iters, transport="tcp", schedule="ring",
-                      sync_plane="p2p", eval_every_iters=10**9,
-                      bucket_bytes=2048, update_backend="pallas")
-    p2p = ps.run_ps(ps.NUMPY_MLP, CFG, cfg, join_timeout_s=900.0)
-    np.testing.assert_array_equal(mono.center, p2p.center)
-    np.testing.assert_array_equal(mono.workers, p2p.workers)
-
-
 # ---------------------------------------------------------------------------
 # (3) accounting
 # ---------------------------------------------------------------------------
@@ -211,55 +185,6 @@ def test_per_bucket_byte_counters_partition_registry_total():
     assert list(measured) == predicted
     assert int(measured.sum()) == int(
         comm_rounds.bytes_from_rounds(rounds, n * 8))
-
-
-# ---------------------------------------------------------------------------
-# (4) the fused kernel at zero tolerance
-# ---------------------------------------------------------------------------
-
-_KERNEL_SCRIPT = r"""
-import numpy as np
-from types import SimpleNamespace
-from repro.core import easgd_flat
-from repro.kernels.elastic_update import (fused_sync_easgd_update,
-                                          fused_sync_sgd_update)
-rng = np.random.default_rng(7)
-for n in (1188, 4096, 131072, 131072 + 777):
-    P, eta, rho, mu = 4, 0.05, 0.07, 0.9
-    cfg = SimpleNamespace(eta=eta, rho=rho, mu=mu, alpha=eta * rho)
-    w = rng.standard_normal(n); g = rng.standard_normal(n)
-    c = rng.standard_normal(n); r = rng.standard_normal(n) * P
-    w_ref, c_ref = w.copy(), c.copy()
-    easgd_flat.worker_step("sync_easgd", w_ref, None, g, c_ref, cfg)
-    easgd_flat.sync_master_easgd(c_ref, r / P, P, cfg)
-    w_new, c_new = fused_sync_easgd_update(w, g, c, r, P, eta, rho,
-                                           interpret=True)
-    assert np.array_equal(w_ref, w_new), ("easgd w", n)
-    assert np.array_equal(c_ref, c_new), ("easgd c", n)
-    v = rng.standard_normal(n)
-    c2_ref, v2_ref = c.copy(), v.copy()
-    easgd_flat.sync_master_sgd(c2_ref, v2_ref, r / P, cfg)
-    c2, v2 = fused_sync_sgd_update(c, v, r, P, eta, mu, interpret=True)
-    assert np.array_equal(c2_ref, c2), ("sgd c", n)
-    assert np.array_equal(v2_ref, v2), ("sgd v", n)
-print("BITWISE-OK")
-"""
-
-
-def test_fused_kernels_match_easgd_flat_zero_tolerance():
-    """The kernels are f64 and share easgd_flat's exact operation order;
-    under the pinned no-FMA ISA (the same flags worker_env ships to
-    pallas-backend workers) XLA cannot contract a·b+c, so the outputs are
-    IDENTICAL bits — asserted with array_equal, no tolerance. Runs in a
-    subprocess because XLA_FLAGS must be set before jax initializes."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = "--xla_cpu_max_isa=SSE4_2"
-    out = subprocess.run([sys.executable, "-c", _KERNEL_SCRIPT], env=env,
-                         capture_output=True, text=True, timeout=600)
-    assert out.returncode == 0, out.stderr
-    assert "BITWISE-OK" in out.stdout
 
 
 # ---------------------------------------------------------------------------

@@ -119,19 +119,6 @@ def test_center_update_forms_agree():
     tree_allclose(s, m)
 
 
-def test_fused_flat_matches_tensor_rules():
-    cfg = EASGDConfig(eta=0.05, rho=0.1, mu=0.9)
-    rng = np.random.RandomState(1)
-    n, P_ = 64, 3
-    w, v, g, c = (jnp.asarray(rng.randn(n), jnp.float32) for _ in range(4))
-    mean_w = jnp.asarray(rng.randn(n), jnp.float32)
-    w2, v2, c2 = easgd.fused_elastic_step_flat(w, v, g, c, mean_w, P_, cfg)
-    v_ref = cfg.mu * v - cfg.eta * g
-    w_ref = w + v_ref - cfg.eta * cfg.rho * (w - c)
-    c_ref = c + cfg.alpha * P_ * (mean_w - c)
-    tree_allclose((w2, v2, c2), (w_ref, v_ref, c_ref))
-
-
 @pytest.mark.parametrize("compression_name", ["none", "bf16", "sign_ef"])
 def test_packed_unpacked_equivalence(compression_name):
     """The packed shard_map exchange == per-tensor reference (exact for
@@ -161,6 +148,58 @@ def test_packed_unpacked_equivalence(compression_name):
         # compressed exchange must still move the center toward the mean
         for k in params:
             assert np.all(np.isfinite(np.asarray(out_p.params[k])))
+
+
+@pytest.mark.parametrize("n_pods,shapes,low", [
+    (1, {"w": (3, 4), "b": (4,)}, False),          # the one-chip cells
+    (2, {"a": (4101,), "b": (8, 128)}, False),     # odd leaf beside aligned
+    (3, {"a": (4101,), "b": (8, 128)}, False),
+    (2, {"a": (4101,), "b": (8, 128)}, True),      # bf16 momentum, center
+])
+def test_packed_exchange_matches_paper_rules(n_pods, shapes, low):
+    """One step through the packed shard_map exchange against the paper's
+    rules: eqs 5-6 (``measgd_worker_update``) per pod, then eq 2
+    (``center_update_from_mean``) from the mean of the pre-update weights.
+    With bfloat16 momentum and center, those two are compared within one
+    bfloat16 rounding of the float32 rules."""
+    from jax.sharding import PartitionSpec as P
+    e = EASGDConfig(eta=0.1, rho=0.5, mu=0.9)
+    dt = jnp.bfloat16 if low else jnp.float32
+    cfg = ElasticConfig(easgd=e, packed=True, momentum_dtype=dt,
+                        center_dtype=dt)
+    keys = iter(jax.random.split(jax.random.PRNGKey(3), 4 * len(shapes)))
+    rand = lambda shape: jax.random.normal(next(keys), shape)
+    params = {k: rand(s) for k, s in shapes.items()}
+    state = elastic_init(params, cfg, n_pods=n_pods)
+    state = state._replace(
+        params={k: rand((n_pods,) + s) for k, s in shapes.items()},
+        momentum={k: rand((n_pods,) + s).astype(dt)
+                  for k, s in shapes.items()})
+    grads = {k: rand((n_pods,) + s) for k, s in shapes.items()}
+    mesh = make_host_mesh((1, 1), ("data", "model"))
+    out = elastic_apply_gradients(state, grads, cfg, mesh=mesh,
+                                  param_specs={k: P() for k in shapes},
+                                  pod_axis=None)
+
+    f32 = lambda t: jax.tree_util.tree_map(
+        lambda x: x.astype(jnp.float32), t)
+    center = f32(state.center)
+    w_want, v_want = easgd.measgd_worker_update(
+        state.params, f32(state.momentum), grads, center, e)
+    mean_w = jax.tree_util.tree_map(lambda w: jnp.mean(w, axis=0),
+                                    state.params)
+    c_want = easgd.center_update_from_mean(center, mean_w, n_pods, e)
+    assert int(out.step) == 1
+    rtol = 2 ** -8 if low else 1e-5
+    for name, got, want, dtype, tol in (
+            ("w", out.params, w_want, jnp.float32, 1e-5),
+            ("v", out.momentum, v_want, dt, rtol),
+            ("c", out.center, c_want, dt, rtol)):
+        for k in shapes:
+            assert got[k].dtype == dtype, (name, k)
+            np.testing.assert_allclose(
+                np.asarray(got[k], np.float32), np.asarray(want[k]),
+                rtol=tol, atol=1e-6, err_msg=f"{name}[{k}]")
 
 
 def test_tau_period():

@@ -83,6 +83,40 @@ def test_decode_matches_teacher_forcing(arch_id):
                                rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("vocab", [300, 512, 1000])
+def test_lm_loss_matches_dense_cross_entropy(vocab):
+    """lm_loss's cross-entropy, chunked along S (three chunks here), and its
+    gradient in the unembedding, against a dense log-softmax over the whole
+    vocabulary, with some positions masked out."""
+    cfg = dataclasses.replace(configs.get("phi3-mini-3.8b").reduced,
+                              n_layers=1, vocab_size=vocab, loss_chunk=16,
+                              compute_dtype=jnp.float32)
+    params = init_params(tfm.model_defs(cfg), jax.random.PRNGKey(0),
+                         jnp.float32)
+    B, S = 2, 24                  # loss_chunk // B = 8 positions a chunk
+    batch = _batch_for(cfg, jax.random.PRNGKey(1), B, S)
+    batch["mask"] = batch["mask"].at[0, -5:].set(0.0).at[1, :3].set(0.0)
+    h, _, _ = tfm.forward(cfg, params, batch["tokens"])
+
+    def dense_ce(w):
+        logp = jax.nn.log_softmax(jnp.einsum("bsd,dv->bsv", h, w), axis=-1)
+        nll = -jnp.take_along_axis(logp, batch["targets"][..., None],
+                                   axis=-1)[..., 0]
+        return jnp.sum(nll * batch["mask"]) / jnp.sum(batch["mask"])
+
+    def chunked_ce(w):
+        return tfm.lm_loss(cfg, {**params, "unembed": w}, batch)[1]["ce"]
+
+    w = params["unembed"]
+    assert w.shape == (cfg.d_model, vocab)
+    got, got_dw = jax.value_and_grad(chunked_ce)(w)
+    want, want_dw = jax.value_and_grad(dense_ce)(w)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(got_dw), np.asarray(want_dw),
+                               rtol=1e-4,
+                               atol=1e-5 * np.abs(np.asarray(want_dw)).max())
+
+
 def test_all_cells_accounted():
     """40 assigned cells; skips only long_500k on pure full-attention archs."""
     cells = configs.cells()

@@ -214,10 +214,13 @@ def test_watchdog_heartbeat_and_stop(tmp_path):
     wd = Watchdog(heartbeat_path=hb, interval_s=0.05,
                   install_signals=False).start_heartbeat()
     import time
-    time.sleep(0.15)
-    assert Watchdog.is_alive(hb, timeout_s=5)
-    wd.should_stop.set()
+    deadline = time.monotonic() + 5.0
+    while not Watchdog.is_alive(hb, timeout_s=5):
+        assert time.monotonic() < deadline, "no heartbeat within 5 s"
+        time.sleep(0.01)
     wd.close()
+    wd._hb_thread.join(timeout=5)
+    assert not wd._hb_thread.is_alive()
 
 
 # ---------------------------------------------------------------------------

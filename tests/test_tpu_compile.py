@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import ops
+from repro.kernels import ssd_chunk
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # what the compiler reports as a v5e's usable HBM
@@ -60,14 +60,6 @@ def smoke():
     return mod
 
 
-def _smoke_param_elems(smoke) -> int:
-    from repro.models import transformer as tfm
-    from repro.models.common import abstract_params
-    _, cfg = smoke.smoke_config()
-    return sum(leaf.size for leaf in jax.tree_util.tree_leaves(
-        abstract_params(tfm.model_defs(cfg), cfg.param_dtype)))
-
-
 def _assert_fits(compiled):
     ma = compiled.memory_analysis()
     need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
@@ -81,26 +73,6 @@ def _compile(fn, *shapes):
     return compiled
 
 
-@pytest.mark.parametrize("aligned", [True, False])
-def test_elastic_update_compiles_at_smoke_packed_size(smoke, one_chip,
-                                                      aligned):
-    from repro.core.packing import ELASTIC_UPDATE_BLOCK
-    n = _smoke_param_elems(smoke)
-    if aligned:       # the packer's layout
-        n = -(-n // ELASTIC_UPDATE_BLOCK) * ELASTIC_UPDATE_BLOCK
-    buf = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
-    fn = functools.partial(ops.elastic_update, eta=0.05, rho=4.5, mu=0.9,
-                           n_workers=4, interpret=False)
-    _compile(fn, *[buf] * 5)
-
-
-def test_fused_cross_entropy_compiles_at_phi3_vocab(one_chip):
-    sd = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    _compile(functools.partial(ops.fused_cross_entropy, interpret=False),
-             sd((8192, 3072), jnp.bfloat16), sd((3072, 32064), jnp.bfloat16),
-             sd((8192,), jnp.int32))
-
-
 @pytest.mark.parametrize("kernel", ["chunk_states", "chunk_output"])
 @pytest.mark.parametrize("direction", ["forward", "backward"])
 def test_ssd_intra_chunk_compiles_at_mamba2_widths(one_chip, kernel,
@@ -108,7 +80,6 @@ def test_ssd_intra_chunk_compiles_at_mamba2_widths(one_chip, kernel,
     """The SSD chunk kernels, and their backward kernels, at the mamba2
     cell's widths: 48 heads of P=64 sharing one B and C of N=128, chunk
     256, 8 rows of 4096; B and C per row, never broadcast per head."""
-    from repro.kernels import ssd_chunk
     R, S, H, P, N, L = 8, 4096, 48, 64, 128, 256
     sd = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     rows, x = sd((R, H, S), jnp.float32), sd((R, S, H * P), jnp.float32)
@@ -129,14 +100,7 @@ def test_ssd_intra_chunk_refuses_unaligned_chunk():
     x = jnp.zeros((1, 64, 2, 64))
     b = jnp.zeros((1, 64, 8))
     with pytest.raises(ValueError, match="multiples of 128"):
-        ops.ssd_intra_chunk(a, x, b, b, chunk=64, interpret=False)
-
-
-def test_flash_attention_compiles_at_phi3_widths(one_chip):
-    qkv = jax.ShapeDtypeStruct((1, 4096, 32, 96), jnp.bfloat16,
-                               sharding=one_chip)
-    _compile(functools.partial(ops.flash_attention, interpret=False),
-             qkv, qkv, qkv)
+        ssd_chunk.ssd_intra_chunk(a, x, b, b, chunk=64, interpret=False)
 
 
 def test_smoke_train_step_fits_one_v5e(smoke, topo):
