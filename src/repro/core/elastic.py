@@ -7,10 +7,12 @@ this automatically from the batch sharding). Across pods, workers exchange
 rules (paper eqs. 1–2 / 5–6), using the paper's three co-design techniques:
 
  1. **Packed single-buffer exchange** (paper §5.2): inside ``shard_map`` each
-    device flattens its *local shards* of every parameter into one contiguous
-    buffer and issues a SINGLE cross-pod all-reduce. Packing in shard-space
-    is a pure local reshape — no resharding traffic — while guaranteeing one
-    collective (one α) instead of one per tensor.
+    device flattens its *local shards* of the one message, δ = W − W̄, into
+    one contiguous buffer and issues a SINGLE cross-pod all-reduce. Packing
+    in shard-space is a pure local reshape — no resharding traffic — while
+    guaranteeing one collective (one α) instead of one per tensor. W, V and
+    W̄ never cross pods and are updated leaf by leaf; with no collective and
+    no codec nothing is packed.
  2. **Device-resident weights** (paper §6.1.2): all state lives in HBM; the
     step never round-trips the host.
  3. **Compute/communication overlap** (paper §6.1.3): the exchange reads only
@@ -155,27 +157,26 @@ def state_specs(param_specs, cfg: ElasticConfig, pod_axis: str | None):
 
 
 # ---------------------------------------------------------------------------
-# flat (packed) math — shared with kernels/ref and tests
+# flat (packed) buffers — only for what crosses the pod axis
 # ---------------------------------------------------------------------------
 
-def _pack_local(tree, pods: int | None = None):
-    """Flatten a pytree of local shards into one contiguous fp32 buffer.
+def _pack_local(tree, pods: int):
+    """Flatten a pytree of local shards, each with a leading pod dim of size
+    ``pods``, into one contiguous fp32 buffer (pods, n); the pod dim stays
+    outer.
 
     Inside shard_map this is a per-device reshape+concat: zero communication.
     This IS the paper's 'single-layer layout' (§5.2) adapted to shard-space.
-    With ``pods`` set, the leading pod dim stays OUTER: result (pods, n).
     """
-    leaves = jax.tree_util.tree_leaves(tree)
-    if pods is None:
-        return jnp.concatenate(
-            [l.reshape(-1).astype(jnp.float32) for l in leaves]
-        )
     return jnp.concatenate(
-        [l.reshape(pods, -1).astype(jnp.float32) for l in leaves], axis=1
+        [l.reshape(pods, -1).astype(jnp.float32)
+         for l in jax.tree_util.tree_leaves(tree)], axis=1
     )
 
 
 def _unpack_local(buf, template, pods: int | None = None):
+    """Slice ``buf`` back into ``template``'s tree and shapes, keeping the
+    buffer's dtype; with ``pods`` the buffer is (pods, n), else (n,)."""
     leaves, treedef = jax.tree_util.tree_flatten(template)
     out, off = [], 0
     for l in leaves:
@@ -185,7 +186,7 @@ def _unpack_local(buf, template, pods: int | None = None):
         else:
             size = l.size // pods
             chunk = lax.slice_in_dim(buf, off, off + size, axis=1)
-        out.append(chunk.reshape(l.shape).astype(l.dtype))
+        out.append(chunk.reshape(l.shape))
         off += size
     return jax.tree_util.tree_unflatten(treedef, out)
 
@@ -210,32 +211,26 @@ def _momentum_only(state: ElasticState, grads, cfg: ElasticConfig):
     return state._replace(step=state.step + 1, params=p_new, momentum=v_new)
 
 
-def _elastic_tensors(state, grads, cfg, mean_w):
-    """Per-tensor eqs 5–6 + eq 2 given the cross-pod mean of W_t."""
-    e = cfg.easgd
-    n_pods = n_pods_of(state)
-    v_new = tree_map(
-        lambda v, g: (e.mu * v.astype(jnp.float32)
-                      - e.eta * g.astype(jnp.float32)).astype(v.dtype),
-        state.momentum, grads,
-    )
+def _elastic_tensors(params, momentum, center, grads, mean_w, e, n_pods):
+    """Eqs 5–6 and 2 leaf by leaf, given the cross-pod mean of W_t:
+    v′ = μv − ηg, w′ = w + v′ − ηρ(w − w̄), w̄′ = w̄ + αP(m − w̄), all in
+    float32 (w′ takes v′ before its rounding to V's dtype), each result
+    stored at its state's dtype. Returns (W′, V′, W̄′)."""
+    f32 = lambda x: x.astype(jnp.float32)
+    v32 = tree_map(lambda v, g: e.mu * f32(v) - e.eta * f32(g),
+                   momentum, grads)
     p_new = tree_map(
-        lambda w, v, c: (
-            w.astype(jnp.float32) + v.astype(jnp.float32)
-            - e.eta * e.rho * (w.astype(jnp.float32)
-                               - c.astype(jnp.float32)[None])
-        ).astype(w.dtype),
-        state.params, v_new, state.center,
+        lambda w, v, c: (f32(w) + v - e.eta * e.rho * (f32(w) - f32(c)[None])
+                         ).astype(w.dtype),
+        params, v32, center,
     )
     a = e.alpha * n_pods
     c_new = tree_map(
-        lambda c, m: (c.astype(jnp.float32)
-                      + a * (m.astype(jnp.float32) - c.astype(jnp.float32))
-                      ).astype(c.dtype),
-        state.center, mean_w,
+        lambda c, m: (f32(c) + a * (f32(m) - f32(c))).astype(c.dtype),
+        center, mean_w,
     )
-    return state._replace(step=state.step + 1, params=p_new, momentum=v_new,
-                          center=c_new)
+    v_new = tree_map(lambda v, old: v.astype(old.dtype), v32, momentum)
+    return p_new, v_new, c_new
 
 
 def _exchange_unpacked(state, grads, cfg):
@@ -245,19 +240,26 @@ def _exchange_unpacked(state, grads, cfg):
     the single message structural."""
     mean_w = tree_map(lambda w: jnp.mean(w.astype(jnp.float32), axis=0),
                       state.params)
-    return _elastic_tensors(state, grads, cfg, mean_w)
+    p_new, v_new, c_new = _elastic_tensors(
+        state.params, state.momentum, state.center, grads, mean_w,
+        cfg.easgd, n_pods_of(state))
+    return state._replace(step=state.step + 1, params=p_new, momentum=v_new,
+                          center=c_new)
 
 
 def _exchange_packed(state, grads, cfg, mesh, param_specs, pod_axis,
                      plan=None):
-    """Packed single-buffer exchange inside shard_map (paper §5.2 + §6.1).
+    """Single-message exchange inside shard_map (paper §5.2 + §6.1).
 
-    Every device: (a) locally flattens its shards of W_t into one buffer,
-    (b) optionally compresses the delta vs W̄, (c) ONE collective over the
-    pod axis using the plan's registered schedule (repro.comm — tree, ring,
-    …), (d) fused elementwise update of W, V, W̄ (eqs 5–6, 2).
+    Every device forms δ = W_t − W̄ per leaf. Where δ crosses the pod axis
+    (the plan has an axis) or a codec encodes it, it (a) packs its shards of
+    δ into one flat buffer, (b) runs ONE collective over the pod axis with
+    the plan's registered schedule (repro.comm — tree, ring, …), compressed
+    with error feedback if the codec says so, and (c) unpacks mean(δ) into
+    W̄'s tree; otherwise mean(δ) is the local mean over the leaf's pod dim,
+    and nothing is packed. Then m = W̄ + mean(δ), and eqs 5–6 and 2 update
+    W, V and W̄ leaf by leaf, in place where the state is donated.
     """
-    e = cfg.easgd
     n_pods = n_pods_of(state)
     pod_in_mesh = pod_axis is not None and pod_axis in mesh.axis_names
     if plan is None:
@@ -266,63 +268,57 @@ def _exchange_packed(state, grads, cfg, mesh, param_specs, pod_axis,
         plan = cfg.exchange_plan(
             axis_name=pod_axis if (n_pods > 1 and pod_in_mesh) else None,
             n_total=n_pods, n_elements=n_elems)
+    flat = plan.axis_name is not None or cfg.compression != "none"
 
     specs = state_specs(param_specs, cfg,
                         pod_axis if (n_pods > 1 and pod_in_mesh) else None)
-    grads_spec = specs.params
-    out_specs = ElasticState(
-        step=P(), params=specs.params, momentum=specs.momentum,
-        center=specs.center, ef_error=specs.ef_error,
-    )
+    ef_spec = specs.ef_error if cfg.compression != "none" else P()
 
     def body(step, params, momentum, center, ef, g):
         # local shards; pod-dim is size n_pods/|pod axis| locally (=1 on the
-        # production mesh). The pod dim stays outer in the packed buffers.
-        # The four phases carry named scopes (elastic.pack, .exchange,
-        # .update, .unpack), so a profiler trace can split the update's
-        # flat copies from its arithmetic.
+        # production mesh). The phases carry named scopes (elastic.pack,
+        # .exchange, .unpack, .update), so a profiler trace can split δ's
+        # flat copies from the arithmetic.
         local_pods = jax.tree_util.tree_leaves(params)[0].shape[0]
-        with jax.named_scope("elastic.pack"):
-            w2 = _pack_local(params, local_pods)  # (local_pods, n_local)
-            g2 = _pack_local(g, local_pods)
-            v2 = _pack_local(momentum, local_pods)
-            c2 = _pack_local(center)[None]        # (1, n_local)
+        delta = lambda w_tree, c_tree: tree_map(
+            lambda w, c: (w.astype(jnp.float32)
+                          - c.astype(jnp.float32)[None]), w_tree, c_tree)
+        ef_new = ef
+        if flat:
+            with jax.named_scope("elastic.pack"):
+                # δ from barrier-renamed W and W̄: XLA then forms it inside
+                # the pack's copy instead of sharing it with the update's
+                # w − w̄ and holding δ's leaves live until the pack
+                d2 = _pack_local(delta(*lax.optimization_barrier(
+                    (params, center))), local_pods)     # (local_pods, n)
+            # --- the ONE cross-pod collective (schedule × compression) ----
+            with jax.named_scope("elastic.exchange"):
+                if cfg.compression != "none":
+                    mean_d, ef2 = plan.reduce_mean_flat(
+                        d2, _pack_local(ef, local_pods))
+                    ef_new = _unpack_local(ef2, ef, local_pods)
+                else:
+                    mean_d, _ = plan.reduce_mean_flat(d2)
+            with jax.named_scope("elastic.unpack"):
+                mean_d = _unpack_local(mean_d, center)
+        else:
+            with jax.named_scope("elastic.update"):
+                mean_d = tree_map(lambda d: jnp.sum(d, axis=0) / float(n_pods),
+                                  delta(params, center))
 
-        # --- the ONE cross-pod collective (plan = schedule × compression) --
-        with jax.named_scope("elastic.exchange"):
-            delta = (w2 - c2)
-            if cfg.compression != "none":
-                ef_flat = _pack_local(ef, local_pods)
-                mean_delta, ef_new2 = plan.reduce_mean_flat(delta, ef_flat)
-                ef_new = _unpack_local(ef_new2, ef, local_pods)
-            else:
-                mean_delta, _ = plan.reduce_mean_flat(delta)
-                ef_new = ef
-            mean_w = c2[0] + mean_delta
-
-        # --- fused elementwise update (eqs 5–6 + 2) ------------------------
+        # --- per-leaf elementwise update (eqs 5–6 + 2) ---------------------
         with jax.named_scope("elastic.update"):
-            v_new = e.mu * v2 - e.eta * g2
-            w_new = w2 + v_new - e.eta * e.rho * (w2 - c2)
-            c_new = c2[0] + e.alpha * n_pods * (mean_w - c2[0])
+            mean_w = tree_map(lambda c, d: c.astype(jnp.float32) + d,
+                              center, mean_d)
+            p_new, v_new, c_new = _elastic_tensors(
+                params, momentum, center, g, mean_w, cfg.easgd, n_pods)
+        return step + 1, p_new, v_new, c_new, ef_new
 
-        with jax.named_scope("elastic.unpack"):
-            return (
-                step + 1,
-                _unpack_local(w_new, params, local_pods),
-                _unpack_local(v_new, momentum, local_pods),
-                _unpack_local(c_new, center),
-                ef_new,
-            )
-
-    in_specs = (P(), specs.params, specs.momentum, specs.center,
-                specs.ef_error if cfg.compression != "none" else P(),
-                grads_spec)
+    in_specs = (P(), specs.params, specs.momentum, specs.center, ef_spec,
+                specs.params)
     shmapped = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs,
-        out_specs=(P(), out_specs.params, out_specs.momentum,
-                   out_specs.center,
-                   out_specs.ef_error if cfg.compression != "none" else P()),
+        out_specs=(P(), specs.params, specs.momentum, specs.center, ef_spec),
         check_vma=False,
     )
     ef_in = state.ef_error if cfg.compression != "none" else jnp.zeros((), jnp.float32)
@@ -359,6 +355,10 @@ def apply_gradients(state: ElasticState, grads, cfg: ElasticConfig,
             gmean = grads
         return _momentum_only(state, gmean, cfg)
 
+    # each gradient reaches the update through HBM, so the update stays a
+    # pass of its own: left free, XLA fuses a leaf's update into the matmul
+    # that writes its gradient, and a trace times the update as fwd/bwd
+    grads = tree_map(lax.optimization_barrier, grads)
     if not cfg.overlap:
         # Sync EASGD1/2 baseline: force the exchange to wait for the
         # gradients (kills the paper's §6.1.3 overlap).

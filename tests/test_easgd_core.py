@@ -6,6 +6,7 @@ to the per-tensor reference. hypothesis is optional (requirements-dev.txt):
 when absent the property tests are skipped and deterministic fallbacks
 keep the invariants covered.
 """
+import re
 import warnings
 
 warnings.filterwarnings("ignore")
@@ -200,6 +201,98 @@ def test_packed_exchange_matches_paper_rules(n_pods, shapes, low):
             np.testing.assert_allclose(
                 np.asarray(got[k], np.float32), np.asarray(want[k]),
                 rtol=tol, atol=1e-6, err_msg=f"{name}[{k}]")
+
+
+def _flat_buffer_update(state, grads, e):
+    """Oracle: the update as one flat-buffer body — pack W, G, V and W̄ into
+    float32 buffers, run eqs 5-6 and 2 on them, slice W, V and W̄ back out
+    at their dtypes. The per-leaf update must equal it bit for bit."""
+    n_pods = n_pods_of(state)
+    leaves = jax.tree_util.tree_leaves
+
+    def pack(tree, pods):
+        return jnp.concatenate(
+            [l.reshape(pods, -1).astype(jnp.float32) for l in leaves(tree)],
+            axis=1)
+
+    def unpack(buf, template):
+        out, off = [], 0
+        for l in leaves(template):
+            size = l.size // buf.shape[0]
+            out.append(buf[:, off:off + size].reshape(l.shape)
+                       .astype(l.dtype))
+            off += size
+        return jax.tree_util.tree_unflatten(
+            jax.tree_util.tree_structure(template), out)
+
+    w2 = pack(state.params, n_pods)
+    g2 = pack(grads, n_pods)
+    v2 = pack(state.momentum, n_pods)
+    c2 = pack(state.center, 1)
+    mean_w = c2[0] + jnp.sum(w2 - c2, axis=0) / float(n_pods)
+    v_new = e.mu * v2 - e.eta * g2
+    w_new = w2 + v_new - e.eta * e.rho * (w2 - c2)
+    c_new = c2[0] + e.alpha * n_pods * (mean_w - c2[0])
+    return (unpack(w_new, state.params), unpack(v_new, state.momentum),
+            unpack(c_new[None], state.center))
+
+
+@pytest.mark.parametrize("low", [False, True], ids=["f32", "bf16"])
+@pytest.mark.parametrize("n_pods", [1, 2, 3])
+def test_per_leaf_update_bitwise_matches_flat_buffers(n_pods, low):
+    """The per-leaf update (packed=True, no pod axis) equals the flat-buffer
+    arithmetic bit for bit on W, V and W̄: one leaf of 4101 elements beside
+    lane-aligned ones, float32 and bfloat16 momentum and center."""
+    from jax.sharding import PartitionSpec as P
+    e = EASGDConfig(eta=0.1, rho=0.5, mu=0.9)
+    dt = jnp.bfloat16 if low else jnp.float32
+    cfg = ElasticConfig(easgd=e, packed=True, momentum_dtype=dt,
+                        center_dtype=dt)
+    shapes = {"a": (4101,), "b": (8, 128), "c": (2, 3, 128)}
+    keys = iter(jax.random.split(jax.random.PRNGKey(11), 5 * len(shapes)))
+    rand = lambda shape: jax.random.normal(next(keys), shape)
+    pod = lambda: {k: rand((n_pods,) + s) for k, s in shapes.items()}
+    state = elastic_init({k: rand(s) for k, s in shapes.items()}, cfg,
+                         n_pods=n_pods)
+    state = state._replace(
+        params=pod(),
+        momentum={k: v.astype(dt) for k, v in pod().items()},
+        center={k: rand(s).astype(dt) for k, s in shapes.items()})
+    grads = pod()
+    mesh = make_host_mesh((1, 1), ("data", "model"))
+    out = jax.jit(lambda s, g: elastic_apply_gradients(
+        s, g, cfg, mesh=mesh, param_specs={k: P() for k in shapes},
+        pod_axis=None))(state, grads)
+    want = jax.jit(lambda s, g: _flat_buffer_update(s, g, e))(state, grads)
+    for name, got, ref in zip("wvc", (out.params, out.momentum, out.center),
+                              want):
+        for k in shapes:
+            assert got[k].dtype == ref[k].dtype, (name, k)
+            np.testing.assert_array_equal(
+                np.asarray(got[k], np.float32),
+                np.asarray(ref[k], np.float32), err_msg=f"{name}[{k}]")
+
+
+@pytest.mark.parametrize("compression_name,packs",
+                         [("none", False), ("sign_ef", True)])
+def test_one_worker_update_packs_nothing(compression_name, packs):
+    """One worker and no codec: the lowered update holds no concatenate and
+    no float32 buffer of the model's element count. A codec encodes the flat
+    buffer, so there the model's buffer is packed (the control)."""
+    from jax.sharding import PartitionSpec as P
+    cfg = ElasticConfig(easgd=EASGDConfig(eta=0.1, rho=0.5, mu=0.9),
+                        packed=True, compression=compression_name)
+    shapes = {"a": (4101,), "b": (8, 128)}
+    n = sum(int(np.prod(s)) for s in shapes.values())
+    state = elastic_init({k: jnp.ones(s) for k, s in shapes.items()}, cfg,
+                         n_pods=1)
+    mesh = make_host_mesh((1, 1), ("data", "model"))
+    text = jax.jit(lambda s, g: elastic_apply_gradients(
+        s, g, cfg, mesh=mesh, param_specs={k: P() for k in shapes},
+        pod_axis=None)).lower(state, state.params).as_text()
+    flat = re.search(rf"tensor<(1x)?{n}xf32>", text)
+    assert ("concatenate" in text) == packs
+    assert (flat is not None) == packs, flat
 
 
 def test_tau_period():

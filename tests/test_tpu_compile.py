@@ -103,11 +103,12 @@ def test_ssd_intra_chunk_refuses_unaligned_chunk():
         ssd_chunk.ssd_intra_chunk(a, x, b, b, chunk=64, interpret=False)
 
 
-def test_smoke_train_step_fits_one_v5e(smoke, topo):
-    """The one-chip step of chip_smoke.py's config (phi3-mini-3.8b at
-    published widths, depth cut) compiles for a v5e and fits its HBM.
-    Batch 1 keeps the compile near 20 s; the step's memory is dominated by
-    the model state and the packed exchange, not by the batch."""
+@pytest.fixture(scope="module")
+def smoke_step(smoke, topo):
+    """chip_smoke.py's one-chip step (phi3-mini-3.8b at published widths,
+    depth cut) compiled for a v5e, and the model's element count. Batch 1
+    keeps the compile near 20 s; the step's memory is dominated by the
+    model state and the update, not by the batch."""
     from repro.launch.mesh import make_host_mesh
     from repro.runtime.train import build_train_step, make_batch_defs
     _, cfg = smoke.smoke_config()
@@ -118,7 +119,27 @@ def test_smoke_train_step_fits_one_v5e(smoke, topo):
     compiled = build.step.lower(
         build.abstract_state,
         make_batch_defs(cfg, 1, 1, smoke.SEQ)).compile()
-    _assert_fits(compiled)
+    return compiled, _model_elements(build)
+
+
+def _model_elements(build) -> int:
+    leaves = jax.tree_util.tree_leaves(build.abstract_state.params)
+    return sum(l.size for l in leaves) // build.n_pods
+
+
+def test_smoke_train_step_fits_one_v5e(smoke_step):
+    """The one-chip step of chip_smoke.py's config compiles for a v5e and
+    fits its HBM."""
+    _assert_fits(smoke_step[0])
+
+
+def test_smoke_step_packs_no_flat_model_buffer(smoke_step):
+    """One worker, no codec: the update runs leaf by leaf, so the compiled
+    step holds no float32 buffer of the model's element count (the flat
+    copies of W, G, V and W̄ are gone)."""
+    compiled, n = smoke_step
+    flat = re.findall(rf"f32\[(?:1,)?{n}\]", compiled.as_text())
+    assert not flat, flat[:3]
 
 
 def test_mamba2_cell_step_fits_one_v5e(topo):
@@ -163,16 +184,12 @@ def _kernel_op_names(text: str) -> list:
             if "custom-call(" in line and "tpu_custom_call" in line]
 
 
-def test_four_pod_step_runs_flash_kernel_per_device(smoke, topo, monkeypatch):
+@pytest.fixture(scope="module")
+def four_pod_build(smoke, topo):
     """The 4-pod phi3 step (chip_smoke.py's config, batch 1 per pod) for a
-    described v5e:2x2: its attention runs the Pallas kernel under the
-    ``attention.flash`` scope, inside the per-pod vmap (where the benchmark's
-    layer rule counts fwd/bwd), with no collective beyond those of the same
-    step on the blocked path, and the step fits a v5e."""
-    import collections
-
+    described v5e:2x2, on a (pod 4, data 1, model 1) mesh: a function that
+    compiles it, and the step as compiled with the model's own kernels."""
     from repro.launch.mesh import make_host_mesh
-    from repro.models import attention
     from repro.runtime.train import build_train_step, make_batch_defs
 
     _, cfg = smoke.smoke_config()
@@ -182,16 +199,29 @@ def test_four_pod_step_runs_flash_kernel_per_device(smoke, topo, monkeypatch):
     def compile_step():
         build = build_train_step(cfg, smoke.elastic_config(), mesh, n_pods=4,
                                  per_pod_batch=1, seq=smoke.SEQ)
-        return build.step.lower(
+        compiled = build.step.lower(
             build.abstract_state,
             make_batch_defs(cfg, 4, 1, smoke.SEQ)).compile()
+        return compiled, _model_elements(build)
+    return compile_step, compile_step()
+
+
+def test_four_pod_step_runs_flash_kernel_per_device(four_pod_build,
+                                                    monkeypatch):
+    """The 4-pod step's attention runs the Pallas kernel under the
+    ``attention.flash`` scope, inside the per-pod vmap (where the benchmark's
+    layer rule counts fwd/bwd), with no collective beyond those of the same
+    step on the blocked path, and the step fits a v5e."""
+    import collections
+
+    from repro.models import attention
 
     def collectives(text):
         return collections.Counter(re.findall(
             r"\b(all-reduce|all-gather|reduce-scatter|all-to-all|"
             r"collective-permute)(?:-start)?\(", text))
 
-    compiled = compile_step()
+    compile_step, (compiled, _) = four_pod_build
     text = compiled.as_text()
     kernel_ops = _kernel_op_names(text)
     assert kernel_ops
@@ -201,6 +231,23 @@ def test_four_pod_step_runs_flash_kernel_per_device(smoke, topo, monkeypatch):
     _assert_fits(compiled)
 
     monkeypatch.setattr(attention, "_kernel_placement", lambda *a: None)
-    blocked = compile_step().as_text()
+    blocked = compile_step()[0].as_text()
     assert "tpu_custom_call" not in blocked
     assert not collectives(text) - collectives(blocked)
+
+
+_ALL_REDUCE = re.compile(
+    r"^\s*(?:ROOT\s+)?%\S+\s*=\s*(\(.*?\)|\S+)\s+all-reduce(?:-start)?\(",
+    re.M)
+
+
+def test_four_pod_step_all_reduces_only_the_model_once(four_pod_build):
+    """The 4-pod step sends one message across pods: exactly one
+    all-reduce carries more than a scalar, and it carries one float32
+    buffer of the model's element count, δ = W − W̄ packed (the step's loss
+    metrics are the only other, scalar, all-reduce)."""
+    _, (compiled, n) = four_pod_build
+    carried = [shape for shapes in _ALL_REDUCE.findall(compiled.as_text())
+               for shape in re.findall(r"\w+\[[\d,]*\]", shapes)
+               if not shape.endswith("[]")]
+    assert carried == [f"f32[{n}]"], carried
